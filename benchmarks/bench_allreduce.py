@@ -59,8 +59,11 @@ def run() -> None:
     emit("allreduce/driver_collect_8p_cpu", t_driver,
          "measured: collect+sum on driver, 8 partitions")
 
+    # the child's 8 devices are virtual CPU ones: held to the CPU, it never
+    # competes with this process for an accelerator
     out = subprocess.run([sys.executable, "-c", _SUBPROC],
                          capture_output=True, text=True,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"),
                          cwd=os.path.dirname(os.path.dirname(
                              os.path.abspath(__file__))))
     if out.returncode == 0:

@@ -826,10 +826,11 @@ threading.Event().wait()
 
 def _subproc_env() -> dict:
     """Child env with the repo's ``src`` on PYTHONPATH (the bench may run
-    from a checkout without an installed package)."""
+    from a checkout without an installed package) and JAX held to the CPU:
+    broker processes are data plane and stay off the accelerator."""
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.pathsep.join(
         [src] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
     return env

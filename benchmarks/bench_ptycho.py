@@ -1,11 +1,12 @@
 """Paper Table II: SHARP-NSLS2 ptychographic solver scaling (512 frames,
 100 iterations; paper: 22.7 / 13.6 / 8.6 s on 1/2/4 K80 nodes).
 
-Measured: RAAR iteration time on this CPU (reduced frames for tractability,
-then scaled to the paper's 512×64² workload by FLOP ratio). Derived: the
-v5e model — per-iteration FLOPs (2 FFTs + overlap products + combine per
-frame) over peak, plus the two psum allreduces of the object/probe
-numerators (paper Fig. 9) over ICI — for 1/2/4 chips, the Table II layout.
+Measured: RAAR iteration time on the default backend, which the row names
+(reduced frames for tractability, then scaled to the paper's 512×64²
+workload by FLOP ratio). Derived: the v5e model — per-iteration FLOPs
+(2 FFTs + overlap products + combine per frame) over peak, plus the two
+psum allreduces of the object/probe numerators (paper Fig. 9) over ICI —
+for 1/2/4 chips, the Table II layout.
 """
 from __future__ import annotations
 
@@ -36,7 +37,7 @@ def run(frames: int = 128, fsize: int = 32, iters: int = 10) -> None:
     n = min(frames, prob.num_frames)
     mags = prob.magnitudes[:n]
     pos = jnp.asarray(prob.positions[:n])
-    cfg = SolverConfig(use_pallas=False)
+    cfg = SolverConfig()
     probe = jnp.asarray(prob.probe_true)
     psi = init_waves(mags, probe)
     obj_shape = prob.object_true.shape
@@ -50,7 +51,8 @@ def run(frames: int = 128, fsize: int = 32, iters: int = 10) -> None:
     psi, probe = one_iter(psi, probe)   # compile
     t = time_call(lambda: jax.block_until_ready(one_iter(psi, probe)),
                   repeats=3)
-    emit("ptycho/raar_iter_cpu", t,
+    backend = jax.default_backend()
+    emit(f"ptycho/raar_iter_{backend}", t,
          f"measured: {n} frames of {fsize}^2 per iteration")
 
     # scale to the paper workload and derive the v5e Table II row
@@ -58,8 +60,8 @@ def run(frames: int = 128, fsize: int = 32, iters: int = 10) -> None:
     scale = (_iteration_flops(paper_frames, paper_fsize, 256)
              / _iteration_flops(n, fsize, 96))
     cpu_100 = t * scale * paper_iters
-    emit("ptycho/100iter_512f_cpu_scaled", cpu_100,
-         f"CPU-scaled paper workload (paper 1 node: 22.7s)")
+    emit(f"ptycho/100iter_512f_{backend}_scaled", cpu_100,
+         f"{backend}-scaled paper workload (paper 1 node: 22.7s)")
     for chips in (1, 2, 4):
         fl = _iteration_flops(paper_frames // chips, paper_fsize, 256)
         by = _iteration_bytes(paper_frames // chips, paper_fsize, 256)

@@ -38,7 +38,7 @@ def run(nray: int = 32, angles: int = 19, nslice: int = 8) -> None:
 
     cfg = TomoConfig(nray=nray,
                      angles=tuple(np.linspace(-75, 75, angles).tolist()),
-                     iterations=1, use_pallas=False)
+                     iterations=1)
     vol, sino = simulate_tilt_series(cfg, nslice)
     A = make_system(nray, np.asarray(cfg.angles))
 

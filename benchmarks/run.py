@@ -96,14 +96,16 @@ def main(argv: list[str] | None = None) -> int:
 
     from benchmarks import (bench_allreduce, bench_ingest, bench_ptycho,
                             bench_streaming, bench_tomo)
+    failed = 0
     for mod in (bench_allreduce, bench_ptycho, bench_tomo, bench_streaming,
                 bench_ingest):
         try:
             mod.run()
         except Exception:
+            failed += 1
             print(f"{mod.__name__},nan,FAILED: "
                   + traceback.format_exc().strip().splitlines()[-1])
-    return 0
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":

@@ -49,13 +49,15 @@ def main() -> None:
 
     from repro.core.broker import BrokerFencedError, OffsetRange
     from repro.data import FailoverBroker, RemoteBroker, ReplicaFollower
+    from repro.utils import cpu_only_children
 
     work = tempfile.mkdtemp(prefix="ha-failover-")
     psock = os.path.join(work, "p.sock")
     proc = mp.get_context("spawn").Process(
         target=primary_main, args=(os.path.join(work, "primary"), psock),
         name="primary-broker")
-    proc.start()
+    with cpu_only_children():      # a broker process is data plane
+        proc.start()
     while not os.path.exists(psock):
         time.sleep(0.01)
 
@@ -102,7 +104,8 @@ def main() -> None:
     zombie = mp.get_context("spawn").Process(
         target=primary_main, args=(os.path.join(work, "primary"), psock),
         name="zombie-primary")
-    zombie.start()
+    with cpu_only_children():
+        zombie.start()
     while not os.path.exists(psock):
         time.sleep(0.01)
     time.sleep(0.1)

@@ -35,9 +35,10 @@ resumed run restores the open window atomically with the consumed offsets
 and must produce the exact per-window reconstruction set an uncrashed run
 produces — no frame lost off the open window, none duplicated.
 
-Run:  PYTHONPATH=src python examples/ptycho_pipeline.py \
-          --frames 512 --obj-size 256 --probe-size 64 --final-iters 60
-(defaults are a few-minute CPU run; --fast shrinks everything)
+Run:  PYTHONPATH=src python examples/ptycho_pipeline.py
+(the defaults are the paper's Table II size: 512 frames of 64² streamed
+over a 256² object; a few seconds on one TPU v5e, minutes on a CPU;
+--fast shrinks everything)
 """
 import argparse
 import json
@@ -59,7 +60,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.apps.ptycho.sim import simulate
+from repro.apps.ptycho.sim import scan_grid, simulate
 from repro.apps.ptycho.solver import (SolverConfig, init_waves, raar_step,
                                       reconstruction_quality)
 from repro.apps.tomo.render import render_phase
@@ -68,6 +69,7 @@ from repro.core import (Broker, ElasticController, LagPolicy,
 from repro.data import (DetectorSource, DurableLogFactory, DurableStateStore,
                         IngestConfig, IngestRunner, MetricsSink,
                         NpzDirectorySink, SinkPolicy, WindowSpec)
+from repro.utils import enable_compile_cache
 
 
 def _restart_consume(root: str, sim_args: tuple, n_frames: int, window: int,
@@ -79,7 +81,7 @@ def _restart_consume(root: str, sim_args: tuple, n_frames: int, window: int,
     positions = jnp.asarray(problem.positions)
     probe = jnp.asarray(problem.probe_true)
     obj_shape = problem.object_true.shape
-    cfg = SolverConfig(beta=0.75, iterations=iters, use_pallas=False)
+    cfg = SolverConfig(beta=0.75, iterations=iters)
 
     factory = DurableLogFactory(os.path.join(root, "wal"))
     broker = Broker(log_factory=factory)
@@ -121,19 +123,22 @@ def run_restart_demo(args) -> None:
     shutil.rmtree(root, ignore_errors=True)
     os.makedirs(root)
     sim_args = (args.obj_size, args.probe_size, args.scan_step)
-    problem = simulate(*sim_args)
-    n_frames = min(args.frames, problem.num_frames)
+    # the parent stays off JAX (the scan grid is numpy): the consumer child
+    # below needs the accelerator, which belongs to one process at a time
+    n_frames = min(args.frames, len(scan_grid(*sim_args)))
     window, batch = args.batch_frames, max(1, args.batch_frames // 3)
     print(f"restart demo: {n_frames} frames -> durable WAL, window {window}, "
           f"{batch} frames/batch")
 
-    # produce the acquisition into the durable log (survives the kill)
+    # produce the acquisition into the durable log (survives the kill): the
+    # DetectorSource's index records, since the consumer re-simulates the
+    # measurements itself
     factory = DurableLogFactory(os.path.join(root, "wal"))
     producer = Broker(log_factory=factory)
     producer.create_topic("frames", 1)
-    source = DetectorSource(problem, max_frames=n_frames)
-    while not source.exhausted:
-        producer.produce_many("frames", source.poll(64), partition=0)
+    producer.produce_many(
+        "frames", [(f"frame-{i:06d}".encode(), i) for i in range(n_frames)],
+        partition=0)
 
     consume = (root, sim_args, n_frames, window, batch, args.iters_per_batch)
     proc = multiprocessing.get_context("spawn").Process(
@@ -185,12 +190,15 @@ def run_restart_demo(args) -> None:
           f"(no frame lost off the open window, none duplicated)")
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> dict | None:
+    """Run the pipeline; returns what it measured (None for --restart)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--frames", type=int, default=512)
     ap.add_argument("--obj-size", type=int, default=256)
     ap.add_argument("--probe-size", type=int, default=64)
-    ap.add_argument("--scan-step", type=int, default=12)
+    ap.add_argument("--scan-step", type=int, default=8,
+                    help="scan raster step in pixels (8 gives 625 positions "
+                         "at the default sizes, enough for --frames 512)")
     ap.add_argument("--frame-interval", type=float, default=0.0,
                     help="seconds between produced frames (paper: 0.05)")
     ap.add_argument("--batch-frames", type=int, default=64)
@@ -207,20 +215,25 @@ def main() -> None:
                          "/metrics.json, /traces, /health) on this port "
                          "while the pipeline runs (0 = ephemeral port)")
     ap.add_argument("--out", default="out")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.fast or args.restart:
         args.frames, args.obj_size, args.probe_size = 81, 96, 32
         args.scan_step, args.batch_frames = 8, 27
         args.final_iters, args.iters_per_batch = 30, 4
     if args.restart:
         run_restart_demo(args)
-        return
+        return None
 
     # ground truth + measurements (the detector)
     problem = simulate(args.obj_size, args.probe_size, args.scan_step)
     n_frames = min(args.frames, problem.num_frames)
     print(f"scan: {problem.num_frames} frames of "
-          f"{problem.frame_shape}; streaming {n_frames}")
+          f"{problem.frame_shape}; streaming {n_frames} of {args.frames} "
+          f"requested")
+    if n_frames < args.frames:
+        print(f"WARNING: the scan holds only {problem.num_frames} positions; "
+              f"lower --scan-step to stream all {args.frames} frames")
 
     source = DetectorSource(problem, max_frames=n_frames,
                             frame_interval=args.frame_interval)
@@ -228,8 +241,7 @@ def main() -> None:
     metrics = MetricsSink()
 
     # reconstruction state (solver warm-starts across micro-batches)
-    cfg = SolverConfig(beta=0.75, iterations=args.final_iters,
-                       use_pallas=False)
+    cfg = SolverConfig(beta=0.75, iterations=args.final_iters)
     positions_all = jnp.asarray(problem.positions)
     mags_all = problem.magnitudes
     probe = jnp.asarray(problem.probe_true)      # known probe mode to start
@@ -349,7 +361,8 @@ def main() -> None:
                           "fourier_err": np.float32(err)})], overwrite=True)
     acq = 0.05 * n_frames
     rep = metrics.report()
-    print(f"\nstreaming phase: {stream_time:.1f}s for {report.records} frames"
+    print(f"\nstreamed {report.records} frames of {args.frames} requested")
+    print(f"streaming phase: {stream_time:.1f}s for {report.records} frames"
           f" ({rep['mean_latency_s']:.2f}s/batch, "
           f"{rep['throughput_rec_per_s']:.0f} rec/s)")
     print(f"total (incl. {args.final_iters} refinement iters): {total:.1f}s "
@@ -390,6 +403,11 @@ def main() -> None:
           f"in {artifact_sink.directory}")
     paths = render_phase(np.asarray(obj), args.out)
     print("artifacts:", paths)
+    return {"frames_requested": args.frames, "frames_streamed": report.records,
+            "batch_errors": state["errs"], "final_error": float(err),
+            "phase_correlation": q, "stream_s": stream_time, "total_s": total,
+            "artifact_dir": artifact_sink.directory,
+            "artifact_keys": artifact_sink.keys_on_disk(), "renders": paths}
 
 
 if __name__ == "__main__":

@@ -81,6 +81,7 @@ def main() -> None:
 
     from repro.core import Broker, Context, StreamingContext
     from repro.data import parse_address, serve_broker
+    from repro.utils import cpu_only_children
 
     # consumer side owns the broker; the server publishes it on a socket
     broker = Broker()
@@ -92,7 +93,8 @@ def main() -> None:
         args=(server.address, args.frames, args.obj_size, args.probe_size,
               args.max_pending),
         name="detector-producer")
-    producer.start()
+    with cpu_only_children():    # the simulator is data plane: off the chip
+        producer.start()
 
     sc = StreamingContext(Context(), broker, batch_interval=0.05,
                           max_records_per_partition=args.batch)

@@ -6,8 +6,8 @@ The paper's four steps, streamed instead of preloaded:
      ProjectionSource (paper: "load the TEM dataset into RDD format");
   2. each micro-batch groups neighbouring slices (paper step 2 —
      repartition by proximity; slices stream in scan order);
-  3. every batch runs the ART sweep (Pallas kernel) partition-parallel —
-     the scheduler retries failures and re-executes stragglers;
+  3. every batch runs the ART sweep (Pallas kernel on a TPU) partition-
+     parallel — the scheduler retries failures and re-executes stragglers;
   4. sub-volumes land in an idempotent NpzDirectorySink (checkpoint store),
      assemble, and render to PNG/NPY (the ParaView/ParaViewWeb stage,
      stubbed per DESIGN.md).
@@ -29,9 +29,11 @@ from repro.apps.tomo.solver import (TomoConfig, reconstruct_slices, residual,
 from repro.core import Broker, Context, NearRealTimePipeline, PipelineConfig
 from repro.core.rdd import TaskScheduler
 from repro.data import MetricsSink, NpzDirectorySink, ProjectionSource
+from repro.utils import enable_compile_cache
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> dict:
+    """Run the pipeline; returns what it measured."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--nray", type=int, default=64)
     ap.add_argument("--nslice", type=int, default=32)
@@ -44,12 +46,13 @@ def main() -> None:
                     help="serve the observability endpoint on this port "
                          "while the pipeline runs (0 = ephemeral port)")
     ap.add_argument("--out", default="out")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = TomoConfig(
         nray=args.nray,
         angles=tuple(np.linspace(-75, 75, args.angles).tolist()),
-        iterations=args.iterations, use_pallas=False)
+        iterations=args.iterations)
 
     # step 1: the tilt series streams in as (slice_index, sinogram_row)
     vol_true, sino = simulate_tilt_series(cfg, args.nslice)
@@ -127,6 +130,9 @@ def main() -> None:
     print(f"sub-volume artifacts: {sink.keys_on_disk()}")
     paths = render_volume(recon, args.out)
     print("artifacts:", paths)
+    return {"residual": r, "volume_error": float(err),
+            "batches": rep["batches"], "slices": args.nslice, "seconds": dt,
+            "artifact_keys": sink.keys_on_disk(), "renders": paths}
 
 
 if __name__ == "__main__":

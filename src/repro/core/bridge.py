@@ -37,7 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.pmi import PMIClient, PMIServer
 from repro.core.rdd import RDD, Context
-from repro.utils import get_logger, make_mesh_compat, shard_map_compat
+from repro.utils import get_logger, make_mesh
 
 log = get_logger(__name__)
 
@@ -45,7 +45,7 @@ log = get_logger(__name__)
 def make_worker_mesh(devices: Sequence[jax.Device] | None = None,
                      axis_name: str = "workers") -> Mesh:
     devs = list(devices) if devices is not None else jax.devices()
-    return make_mesh_compat((len(devs),), (axis_name,), devices=devs)
+    return make_mesh((len(devs),), (axis_name,), devices=devs)
 
 
 class MPIBridge:
@@ -98,8 +98,10 @@ class MPIBridge:
         any ``jax.lax`` collective with ``axis_name``."""
         in_specs = P(self.axis_name)
         out_specs = P(self.axis_name) if out_specs is None else out_specs
-        sm = shard_map_compat(fn, mesh=self.mesh,
-                              in_specs=in_specs, out_specs=out_specs)
+        # check_vma=False: a Pallas kernel in a rank's program declares its
+        # outputs without the varying-axes annotation that check demands
+        sm = jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return jax.jit(sm)
 
     def run(self, rdd: RDD, fn: Callable[..., Any],

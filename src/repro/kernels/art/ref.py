@@ -10,7 +10,8 @@ def art_sweep_ref(A: jax.Array, b: jax.Array, inv_rip: jax.Array,
                   iters: int = 1) -> jax.Array:
     def row_step(f, xs):
         row, bj, irip = xs
-        resid = (bj - jnp.dot(row, f)) * irip
+        # full f32 dot: on a TPU the default matmul precision is bf16
+        resid = (bj - jnp.dot(row, f, precision="highest")) * irip
         return f + beta * resid * row, None
 
     def sweep(f, _):

@@ -1,0 +1,15 @@
+"""The one kernel-dispatch rule shared by every Pallas op wrapper."""
+from __future__ import annotations
+
+import jax
+
+
+def kernel_mode(use_pallas: bool | None = None) -> tuple[bool, bool]:
+    """``(run the Pallas kernel, run it in interpret mode)``.
+
+    ``use_pallas=None`` picks the kernel on a TPU and the pure-jnp reference
+    elsewhere; ``True``/``False`` force one or the other. Interpret mode is
+    the CPU test path only: on a TPU a kernel always runs compiled.
+    """
+    on_tpu = jax.default_backend() == "tpu"
+    return (on_tpu if use_pallas is None else use_pallas), not on_tpu

@@ -8,11 +8,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch
 from repro.kernels.flash_attention import kernel, ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -20,7 +17,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_q: int = 256, block_kv: int = 512,
                     use_pallas: bool | None = None) -> jax.Array:
     """q, k, v: (B, S, H, hd) (KV already repeated to H). Causal."""
-    use_pallas = _on_tpu() if use_pallas is None else use_pallas
+    use_pallas, interpret = dispatch.kernel_mode(use_pallas)
     B, S, H, hd = q.shape
 
     def to_bhsd(x):
@@ -38,7 +35,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if use_pallas:
         out = kernel.flash_attention_bhsd(qb, kb, vb, block_q=bq,
                                           block_kv=bkv, causal=True,
-                                          interpret=not _on_tpu())
+                                          interpret=interpret)
     else:
         out = ref.attention_ref(qb, kb, vb, causal=True)
     out = out[:, :S]

@@ -4,17 +4,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch
 from repro.kernels.raar import kernel, ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def raar_combine(psi: jax.Array, p1: jax.Array, p21: jax.Array,
                  p2: jax.Array, beta: float = 0.75,
                  use_pallas: bool | None = None) -> jax.Array:
-    use_pallas = _on_tpu() if use_pallas is None else use_pallas
+    use_pallas, interpret = dispatch.kernel_mode(use_pallas)
     if not use_pallas:
         return ref.raar_combine_complex(psi, p1, p21, p2, beta)
     planes = []
@@ -22,5 +19,5 @@ def raar_combine(psi: jax.Array, p1: jax.Array, p21: jax.Array,
         planes += [jnp.real(z).astype(jnp.float32),
                    jnp.imag(z).astype(jnp.float32)]
     o_re, o_im = kernel.raar_combine(*planes, beta=beta,
-                                     interpret=not _on_tpu())
+                                     interpret=interpret)
     return jax.lax.complex(o_re, o_im)

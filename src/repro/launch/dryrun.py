@@ -31,7 +31,6 @@ import jax
 import numpy as np
 
 from repro.configs import (SHAPES, all_archs, applicable_shapes, get_config)
-from repro.utils import peak_memory_bytes
 from repro.configs.base import ModelConfig, OptimizerConfig, ShapeConfig
 from repro.launch import mesh as mesh_lib
 from repro.launch.hlocost import hlo_cost
@@ -145,7 +144,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         print(f"    args={ma.argument_size_in_bytes/2**30:.3f}GiB "
               f"out={ma.output_size_in_bytes/2**30:.3f}GiB "
               f"temp={ma.temp_size_in_bytes/2**30:.3f}GiB "
-              f"peak={peak_memory_bytes(ma)/2**30:.3f}GiB per device")
+              f"peak={ma.peak_memory_in_bytes/2**30:.3f}GiB per device")
         ca = compiled.cost_analysis()
         print(f"    cost_analysis: flops={ca.get('flops', 0):.3e} "
               f"bytes={ca.get('bytes accessed', 0):.3e} (body-once, see walker)")
@@ -153,7 +152,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             "argument_bytes": ma.argument_size_in_bytes,
             "output_bytes": ma.output_size_in_bytes,
             "temp_bytes": ma.temp_size_in_bytes,
-            "peak_bytes": peak_memory_bytes(ma),
+            "peak_bytes": ma.peak_memory_in_bytes,
         }
         rec["xla_cost"] = {"flops": ca.get("flops", 0.0),
                            "bytes": ca.get("bytes accessed", 0.0)}

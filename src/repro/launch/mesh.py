@@ -10,18 +10,18 @@ from __future__ import annotations
 
 import jax
 
-from repro.utils import make_mesh_compat
+from repro.utils import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2) -> jax.sharding.Mesh:
     """Small mesh for multi-device subprocess tests."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 # Hardware model for the roofline (TPU v5e-class, per assignment):

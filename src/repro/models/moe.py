@@ -28,7 +28,6 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models.layers import activation, normal_init, split_keys
 from repro.parallel.sharding import logical_constraint
-from repro.utils import shard_map_compat
 
 
 def padded_experts(config: ModelConfig) -> int:
@@ -234,7 +233,7 @@ def moe_layer_a2a(x: jax.Array, params: dict, config: ModelConfig
     in_specs = (P(bspec, "model", None), P(None, None),
                 P(axes, None, None), P(axes, None, None),
                 P(axes, None, None))
-    out, aux = shard_map_compat(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(bspec, "model", None), P()),

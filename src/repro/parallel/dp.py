@@ -31,7 +31,7 @@ from repro.configs.base import ModelConfig, OptimizerConfig
 from repro.models.registry import get_model
 from repro.optim.adamw import lr_schedule
 from repro.parallel.sharding import use_mesh
-from repro.utils import get_logger, shard_map_compat
+from repro.utils import get_logger
 
 log = get_logger(__name__)
 
@@ -158,10 +158,10 @@ def build_dp_train_step(config: ModelConfig, opt: OptimizerConfig,
     state_specs = {"params": P(),
                    "opt": {"m": P(axes), "v": P(axes), "master": P(axes),
                            "step": P()}}
-    sm = shard_map_compat(step, mesh=mesh,
-                          in_specs=(state_specs, P(axes)),
-                          out_specs=(state_specs, P()),
-                          check_vma=False)
+    sm = jax.shard_map(step, mesh=mesh,
+                       in_specs=(state_specs, P(axes)),
+                       out_specs=(state_specs, P()),
+                       check_vma=False)
     return jax.jit(sm, donate_argnums=(0,)), state_specs
 
 

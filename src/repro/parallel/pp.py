@@ -27,7 +27,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.utils import get_logger, shard_map_compat
+from repro.utils import get_logger
 
 log = get_logger(__name__)
 
@@ -91,7 +91,7 @@ def pipeline_layers(run_block: Callable[[jax.Array, Any], jax.Array],
         return x_out
 
     spec_layers = jax.tree_util.tree_map(lambda _: P(axis), layer_params)
-    pipe = shard_map_compat(
+    pipe = jax.shard_map(
         functools.partial(gpipe_apply, stage_fn, n_stages=n_stages,
                           axis=axis),
         mesh=mesh,

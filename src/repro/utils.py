@@ -1,4 +1,5 @@
-"""Shared utilities: logging, timing, pytree helpers, numeric helpers."""
+"""Shared utilities: mesh, compile cache and child-process helpers, logging,
+timing, pytree helpers, numeric helpers."""
 from __future__ import annotations
 
 import contextlib
@@ -15,45 +16,52 @@ import numpy as np
 
 _LOG_FORMAT = "%(asctime)s %(levelname).1s %(name)s: %(message)s"
 
-# -- jax version compat ------------------------------------------------------
-# shard_map graduated from jax.experimental (with kwargs renamed), and
-# make_mesh grew axis_types, in newer jax; these shims keep one call site per
-# API working on both.
-
-def shard_map_compat(fn, mesh, in_specs, out_specs, **kwargs):
-    """jax.shard_map on new jax; jax.experimental.shard_map on old, with
-    ``check_vma``->``check_rep`` and ``axis_names``->``auto`` translated."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map
-    if "check_vma" in kwargs:                    # renamed (same meaning)
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    if "axis_names" in kwargs:                   # old API names the complement
-        manual = set(kwargs.pop("axis_names"))
-        kwargs["auto"] = frozenset(set(mesh.axis_names) - manual)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kwargs)
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
-def peak_memory_bytes(memory_analysis) -> int:
-    """CompiledMemoryStats.peak_memory_in_bytes where available; otherwise
-    the argument+output+temp estimate older jaxlib exposes."""
-    peak = getattr(memory_analysis, "peak_memory_in_bytes", None)
-    if peak is not None:
-        return int(peak)
-    ma = memory_analysis
-    return int(ma.argument_size_in_bytes + ma.output_size_in_bytes
-               + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
-
-
-def make_mesh_compat(shape, axes, **kwargs):
-    """jax.make_mesh with axis_types=Auto where supported (Auto is the
-    default behavior on versions without the parameter)."""
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs.setdefault("axis_types",
-                          (jax.sharding.AxisType.Auto,) * len(axes))
+def make_mesh(shape, axes, **kwargs):
+    """jax.make_mesh with every axis explicitly ``AxisType.Auto``."""
+    kwargs.setdefault("axis_types", (jax.sharding.AxisType.Auto,) * len(axes))
     return jax.make_mesh(shape, axes, **kwargs)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
+    other directory is set here. Otherwise the cache lives at the fixed
+    path ``<checkout>/.jax_cache``: a directory that moved between runs
+    would never hit. Call it before the first compilation of the process.
+    """
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = os.path.join(_REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # kernels and small step programs compile in well under JAX's default
+    # one-second floor; cache them too
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return cache_dir
+
+
+@contextlib.contextmanager
+def cpu_only_children() -> Iterator[None]:
+    """Processes started inside this block inherit ``JAX_PLATFORMS=cpu``.
+
+    An accelerator belongs to one process at a time: data-plane children
+    (detector producers, broker processes) started from a process that
+    owns the chip must stay off it. Only start processes in the block; the
+    parent's own JAX read its platform when it was imported.
+    """
+    old = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        yield
+    finally:
+        if old is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = old
 
 
 def get_logger(name: str) -> logging.Logger:

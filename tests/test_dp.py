@@ -11,13 +11,13 @@ def test_dp_step_matches_gspmd_trainer():
         from repro.configs import get_config
         from repro.configs.base import OptimizerConfig
         from repro.parallel.dp import build_dp_train_step, init_dp_opt_state
-        from repro.utils import make_mesh_compat
+        from repro.utils import make_mesh
         from repro.training import build_train_step, init_state
 
         cfg = get_config("internlm2-1.8b", reduced=True)
         opt = OptimizerConfig(lr=1e-3, warmup_steps=1, total_steps=50,
                               zero1=False, grad_clip=1.0, weight_decay=0.0)
-        mesh = make_mesh_compat((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         key = jax.random.PRNGKey(0)
         state_ref = init_state(key, cfg, opt)
         gspmd_step = jax.jit(build_train_step(cfg, opt))
@@ -50,12 +50,12 @@ def test_dp_compressed_training_converges():
         from repro.configs import get_config
         from repro.configs.base import OptimizerConfig
         from repro.parallel.dp import build_dp_train_step, init_dp_opt_state
-        from repro.utils import make_mesh_compat
+        from repro.utils import make_mesh
 
         cfg = get_config("internlm2-1.8b", reduced=True)
         opt = OptimizerConfig(lr=1e-3, warmup_steps=2, total_steps=40,
                               zero1=False)
-        mesh = make_mesh_compat((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         step, _ = build_dp_train_step(cfg, opt, mesh, compression="int8")
         key = jax.random.PRNGKey(0)
         from repro.models.registry import get_model

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import dispatch
 from repro.kernels.art import ops as art_ops
 from repro.kernels.art import ref as art_ref
 from repro.kernels.flash_attention import kernel as fa_kernel
@@ -20,6 +21,17 @@ from repro.kernels.raar import ref as raar_ref
 def _planes(key, shape, dtype=jnp.float32, n=1):
     keys = jax.random.split(key, n)
     return [jax.random.normal(k, shape, dtype) for k in keys]
+
+
+def test_dispatch_never_interprets_on_tpu(monkeypatch):
+    """Off the TPU: oracle by default, interpret mode when a test forces the
+    kernel. On a TPU: the kernel by default, and always compiled."""
+    assert dispatch.kernel_mode() == (False, True)
+    assert dispatch.kernel_mode(True) == (True, True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert dispatch.kernel_mode() == (True, False)
+    assert dispatch.kernel_mode(True) == (True, False)
+    assert dispatch.kernel_mode(False) == (False, False)
 
 
 # -- modulus -------------------------------------------------------------------
@@ -90,9 +102,11 @@ def test_overlap_matches_complex_ref():
 
 
 # -- art ----------------------------------------------------------------------
-@pytest.mark.parametrize("nrow,ncol", [(8, 16), (20, 12), (32, 64)])
+@pytest.mark.parametrize("nrow,ncol", [(8, 16), (20, 12), (32, 64),
+                                       (13, 128)])
 @pytest.mark.parametrize("iters", [1, 3])
 def test_art_sweep(nrow, ncol, iters):
+    """Rows stream in blocks of 8; 20 and 13 rows pin the padding."""
     key = jax.random.PRNGKey(4)
     A = jax.random.normal(key, (nrow, ncol))
     f_true = jax.random.normal(jax.random.PRNGKey(5), (ncol,))

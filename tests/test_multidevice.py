@@ -7,8 +7,6 @@ import subprocess
 import sys
 import textwrap
 
-import pytest
-
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -120,8 +118,7 @@ def test_elastic_training_recovery():
             ys = _np.stack(_np.split(y[: rows * n], n))
             sharding = NamedSharding(bridge.mesh, P("workers"))
             w_dev = w
-            from repro.utils import shard_map_compat
-            prog = jax.jit(shard_map_compat(
+            prog = jax.jit(jax.shard_map(
                 grad_prog, mesh=bridge.mesh,
                 in_specs=(P("workers"), P("workers")),
                 out_specs=P()))
@@ -160,12 +157,12 @@ def test_hlocost_collectives_at_mesh_sizes():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.launch.hlocost import hlo_cost
-        from repro.utils import make_mesh_compat, shard_map_compat
+        from repro.utils import make_mesh
         for n in (2, 4, 8):
-            mesh = make_mesh_compat((n,), ("d",))
-            f = jax.jit(shard_map_compat(lambda x: jax.lax.psum(x, "d"),
-                                         mesh=mesh, in_specs=P("d"),
-                                         out_specs=P()))
+            mesh = make_mesh((n,), ("d",))
+            f = jax.jit(jax.shard_map(lambda x: jax.lax.psum(x, "d"),
+                                      mesh=mesh, in_specs=P("d"),
+                                      out_specs=P()))
             c = f.lower(jax.ShapeDtypeStruct((n, 1024), jnp.float32)).compile()
             cost = hlo_cost(c.as_text())
             want = 2 * 4096 * (n - 1) / n
@@ -185,8 +182,8 @@ def test_dryrun_cell_smoke_small_mesh():
         from repro.configs.base import ShapeConfig
         from repro.training import lower_cell
         from repro.launch.hlocost import hlo_cost
-        from repro.utils import make_mesh_compat
-        mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+        from repro.utils import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         for arch in ("internlm2-1.8b", "granite-moe-3b-a800m"):
             cfg = get_config(arch, reduced=True)
             shape = ShapeConfig("smoke_train", 64, 8, "train")
@@ -195,8 +192,7 @@ def test_dryrun_cell_smoke_small_mesh():
             cost = hlo_cost(compiled.as_text(), pod_size=4)
             assert cost["flops"] > 0
             ma = compiled.memory_analysis()
-            from repro.utils import peak_memory_bytes
-            assert peak_memory_bytes(ma) > 0
+            assert ma.peak_memory_in_bytes > 0
         print("OK")
     """)
     assert "OK" in out
@@ -210,8 +206,8 @@ def test_moe_a2a_matches_baseline_dispatch():
         from repro.configs import get_config
         from repro.models import moe as moe_lib
         from repro.parallel.sharding import ShardingRules, use_mesh
-        from repro.utils import make_mesh_compat
-        mesh = make_mesh_compat((4, 2), ("data", "model"))
+        from repro.utils import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg0 = get_config("granite-moe-3b-a800m", reduced=True)
         cfg0 = cfg0.replace(capacity_factor=4.0)
         cfg_a2a = cfg0.replace(sharding_overrides={
@@ -236,23 +232,14 @@ def test_moe_a2a_matches_baseline_dispatch():
     assert "OK" in out
 
 
-def _has_partial_auto_shard_map() -> bool:
-    # partial-manual shard_map (axis_names=...) needs graduated jax.shard_map;
-    # on older jaxlib XLA rejects it with "PartitionId ... UNIMPLEMENTED".
-    import jax
-    return hasattr(jax, "shard_map")
-
-
-@pytest.mark.skipif(not _has_partial_auto_shard_map(),
-                    reason="partial-auto shard_map unsupported on this jax")
 def test_gpipe_pipeline_matches_sequential():
     """GPipe over a 2-stage 'pod' axis == sequential layer stack (fwd+bwd)."""
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.parallel.pp import pipeline_layers
-        from repro.utils import make_mesh_compat
-        mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+        from repro.utils import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         L, B, S, D = 4, 8, 16, 32
         key = jax.random.PRNGKey(0)
         W = jax.random.normal(key, (L, D, D)) * 0.1
