@@ -1,0 +1,9 @@
+"""Device time of the RAAR step's ``raar/exit_waves`` phase (the exit waves
+from the new probe and object) per refinement iteration, in ms: chip 0's ops
+in that phase (``chipbench.program_trace``) inside the
+``bench.ptycho.refine`` spans, over the refinement iterations."""
+from chipbench import program_trace
+
+
+def read(run):
+    return program_trace.phase_ms(run, "exit_waves")

@@ -271,9 +271,6 @@ def main(argv: list[str] | None = None) -> dict | None:
             state["iteration"] += 1
         state.update(psi=psi, obj=obj, n_seen=n_new)
         state["errs"].append(float(err))
-        print(f"  batch {info.index}: {n_new}/{n_frames} frames, "
-              f"fourier err {float(err):.4f}, "
-              f"proc {info.processing_time:.2f}s")
         # keyed result -> idempotent sink (replays overwrite, not duplicate)
         return [(f"batch-{info.index:06d}",
                  {"fourier_err": np.float32(err),
@@ -292,6 +289,16 @@ def main(argv: list[str] | None = None) -> dict | None:
         # artifact store on its own delivery lane: a slow disk can no longer
         # stall the batch loop, and transient write errors retry twice
         sinks=[metrics, (artifact_sink, SinkPolicy.retry(2, queue_depth=32))])
+
+    def print_batch(info):
+        # a serial sink: it runs once the stream has stamped the batch's
+        # processing time, which the batch function itself cannot see yet
+        if info.result is not None:
+            print(f"  batch {info.index}: {state['n_seen']}/{n_frames} "
+                  f"frames, fourier err {state['errs'][-1]:.4f}, "
+                  f"proc {info.processing_time:.2f}s")
+
+    pipeline.streaming.add_sink(print_batch)
 
     runner = controller = policy = None
     if args.elastic:

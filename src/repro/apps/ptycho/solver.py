@@ -16,6 +16,13 @@ Per iteration (SHARP schedule — one overlap solve per iteration):
 ``shard_map`` over a worker mesh (the Spark-MPI bridge path — the paper's
 deployment), and inside the streaming pipeline (frames arriving in
 micro-batches).
+
+Each phase of a step runs under a ``jax.named_scope``: ``raar/far_field``
+(the FFTs and the error sums), ``raar/modulus`` (step 1's projection),
+``raar/object_solve`` and ``raar/probe_solve`` (step 2), ``raar/exit_waves``
+(step 3) and ``raar/combine`` (step 4). The scopes are metadata: the
+compiled program keeps its ops and fusions, and a profiler trace gives each
+op's time to its phase on all three paths.
 """
 from __future__ import annotations
 
@@ -42,6 +49,15 @@ class SolverConfig:
     use_pallas: bool | None = None  # None = auto by backend
 
 
+def _phase(name: str):
+    """Name scope of one phase of the iteration, ``raar/<name>``. It is
+    metadata only: it reaches each compiled op's ``op_name`` and, in a
+    profiler trace, the op's ``tf_op``, so device time splits by phase
+    (``far_field``, ``modulus``, ``object_solve``, ``probe_solve``,
+    ``exit_waves``, ``combine``) on every path that calls ``raar_step``."""
+    return jax.named_scope(f"raar/{name}")
+
+
 def _patch_indices(positions: jax.Array, frame: int):
     iy = positions[:, 0, None, None] + jnp.arange(frame)[None, :, None]
     ix = positions[:, 1, None, None] + jnp.arange(frame)[None, None, :]
@@ -60,30 +76,32 @@ def overlap_update(psi: jax.Array, positions: jax.Array, probe: jax.Array,
     With ``axis_name``, partial sums are psum'd across the worker axis —
     the paper's MPI_Allreduce (Fig. 9)."""
     F, h, w = psi.shape
-    iy, ix = _patch_indices(positions, h)
-
-    # object update: O = Σ ψ_j P* / Σ |P|²
-    num_o, den_o = overlap_ops.overlap_products(
-        psi, jnp.broadcast_to(probe[None], psi.shape), use_pallas=use_pallas)
-    num = jnp.zeros(obj_shape, psi.dtype).at[iy, ix].add(num_o)
-    den = jnp.zeros(obj_shape, jnp.float32).at[iy, ix].add(den_o)
-    if axis_name:
-        num = jax.lax.psum(num, axis_name)
-        den = jax.lax.psum(den, axis_name)
-    obj = num / (den + eps)
+    with _phase("object_solve"):
+        iy, ix = _patch_indices(positions, h)
+        # object update: O = Σ ψ_j P* / Σ |P|²
+        num_o, den_o = overlap_ops.overlap_products(
+            psi, jnp.broadcast_to(probe[None], psi.shape),
+            use_pallas=use_pallas)
+        num = jnp.zeros(obj_shape, psi.dtype).at[iy, ix].add(num_o)
+        den = jnp.zeros(obj_shape, jnp.float32).at[iy, ix].add(den_o)
+        if axis_name:
+            num = jax.lax.psum(num, axis_name)
+            den = jax.lax.psum(den, axis_name)
+        obj = num / (den + eps)
 
     if not update_probe:
         return obj, probe
-    # probe update: P = Σ ψ_j O*_patch / Σ |O_patch|²
-    patches = obj[iy, ix]
-    num_p, den_p = overlap_ops.overlap_products(psi, patches,
-                                                use_pallas=use_pallas)
-    nump = jnp.sum(num_p, axis=0)
-    denp = jnp.sum(den_p, axis=0)
-    if axis_name:
-        nump = jax.lax.psum(nump, axis_name)
-        denp = jax.lax.psum(denp, axis_name)
-    new_probe = nump / (denp + eps)
+    with _phase("probe_solve"):
+        # probe update: P = Σ ψ_j O*_patch / Σ |O_patch|²
+        patches = obj[iy, ix]
+        num_p, den_p = overlap_ops.overlap_products(psi, patches,
+                                                    use_pallas=use_pallas)
+        nump = jnp.sum(num_p, axis=0)
+        denp = jnp.sum(den_p, axis=0)
+        if axis_name:
+            nump = jax.lax.psum(nump, axis_name)
+            denp = jax.lax.psum(denp, axis_name)
+        new_probe = nump / (denp + eps)
     return obj, new_probe
 
 
@@ -95,19 +113,25 @@ def raar_step(psi: jax.Array, mag: jax.Array, positions: jax.Array,
     """One RAAR iteration. Returns (psi', obj, probe, fourier_error)."""
     up = config.use_pallas
     # π₁: modulus projection
-    far = jnp.fft.fft2(psi)
-    err = jnp.sum(jnp.square(jnp.abs(far) - mag))
-    norm = jnp.sum(jnp.square(mag))
-    if axis_name:
-        err = jax.lax.psum(err, axis_name)
-        norm = jax.lax.psum(norm, axis_name)
-    far_proj = modulus_ops.modulus_project(far, mag, use_pallas=up)
-    psi1 = jnp.fft.ifft2(far_proj)
+    with _phase("far_field"):
+        far = jnp.fft.fft2(psi)
+        err = jnp.sum(jnp.square(jnp.abs(far) - mag))
+        norm = jnp.sum(jnp.square(mag))
+        if axis_name:
+            err = jax.lax.psum(err, axis_name)
+            norm = jax.lax.psum(norm, axis_name)
+    with _phase("modulus"):
+        far_proj = modulus_ops.modulus_project(far, mag, use_pallas=up)
+    with _phase("far_field"):
+        psi1 = jnp.fft.ifft2(far_proj)
 
     # overlap (eqs. 4-5) on the projected waves
-    update_probe = jnp.asarray(iteration) >= config.probe_update_start \
-        if not isinstance(iteration, int) else \
-        iteration >= config.probe_update_start
+    if isinstance(iteration, int):
+        update_probe = iteration >= config.probe_update_start
+    else:
+        with _phase("probe_solve"):
+            update_probe = (jnp.asarray(iteration)
+                            >= config.probe_update_start)
     if isinstance(update_probe, bool):
         obj, new_probe = overlap_update(psi1, positions, probe, obj_shape,
                                         config.eps, axis_name,
@@ -116,16 +140,19 @@ def raar_step(psi: jax.Array, mag: jax.Array, positions: jax.Array,
         obj, probe_candidate = overlap_update(psi1, positions, probe,
                                               obj_shape, config.eps,
                                               axis_name, True, use_pallas=up)
-        new_probe = jnp.where(update_probe, probe_candidate, probe)
+        with _phase("probe_solve"):
+            new_probe = jnp.where(update_probe, probe_candidate, probe)
 
     # π₂π₁ψ with the refreshed (P, O)
-    iy, ix = _patch_indices(positions, psi.shape[-1])
-    p21 = new_probe[None] * obj[iy, ix]
+    with _phase("exit_waves"):
+        iy, ix = _patch_indices(positions, psi.shape[-1])
+        p21 = new_probe[None] * obj[iy, ix]
 
     # RAAR combine (eq. 7); π₂ψ ≈ π₂π₁ψ under the fixed-(P,O) projector
-    new_psi = raar_ops.raar_combine(psi, psi1, p21, p21, config.beta,
-                                    use_pallas=up)
-    rel_err = jnp.sqrt(err / jnp.maximum(norm, 1e-12))
+    with _phase("combine"):
+        new_psi = raar_ops.raar_combine(psi, psi1, p21, p21, config.beta,
+                                        use_pallas=up)
+        rel_err = jnp.sqrt(err / jnp.maximum(norm, 1e-12))
     return new_psi, obj, new_probe, rel_err
 
 

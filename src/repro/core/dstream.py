@@ -39,6 +39,8 @@ from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Any, Callable, Sequence
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.broker import Broker, OffsetRange, create_rdd
 from repro.core.rdd import RDD, Context
 from repro.utils import get_logger
@@ -454,11 +456,14 @@ class StreamingContext:
             # heartbeat / rejoin as due; an ownership change lands through
             # _apply_group_assignment before ranges are computed
             self.group_member.maintain()
-        t_pump = time.perf_counter()
-        if self._sources:
-            self._pump_sources()
-        ranges = self._pending_ranges()
-        pump_s = time.perf_counter() - t_pump
+        # the pump runs before the batch's recorder exists (it is what finds
+        # out whether there is a batch), so it carries its own profiler span
+        with TraceAnnotation("repro.pump", batch_index=self._batch_index):
+            t_pump = time.perf_counter()
+            if self._sources:
+                self._pump_sources()
+            ranges = self._pending_ranges()
+            pump_s = time.perf_counter() - t_pump
         if not ranges:
             # no span for idle probes: the trace log holds batches, and an
             # idle poll loop would otherwise drown them
@@ -466,50 +471,55 @@ class StreamingContext:
         info = BatchInfo(index=self._batch_index, ranges=ranges,
                          num_records=sum(r.count() for r in ranges),
                          scheduled_at=self._clock())
-        rec = self.traces.begin(self._batch_index, info.num_records)
-        rec.add("pump", pump_s)
-        per_topic: dict[str, list[OffsetRange]] = {}
-        for r in ranges:
-            per_topic.setdefault(r.topic, []).append(r)
-        # codec decode first (per-topic payload codecs are self-describing,
-        # see repro.data.codec), then the subscriber's own value_decoder
-        from repro.data.codec import compose_decoder
-        decoder = compose_decoder(self._decoder)
-        topic_rdds = [create_rdd(self.context, self.broker, rs, decoder)
-                      for rs in per_topic.values()]
-        union = (topic_rdds[0].union(*topic_rdds[1:])
-                 if len(topic_rdds) > 1 else topic_rdds[0])
-        # snapshot attached window state so a failed batch fn / serial sink
-        # rolls back cleanly: the replay must not find records half-pushed
-        rollback = [(w, w.state()) for _, w in self._window_states]
-        t0 = time.perf_counter()
-        try:
-            with rec.stage("batch_fn"):
-                if self._batch_fn is not None:
-                    info.result = self._batch_fn(union, info)
-            info.processing_time = time.perf_counter() - t0
-            # Serial sinks run BEFORE the commit: a raising sink aborts the
-            # commit, so the batch (windower pushes included, via the
-            # rollback above) replays — the at-least-once contract the module
-            # docstring promises. Delivery lanes below keep their documented
-            # <= queue-depth post-commit crash window.
-            with rec.stage("sinks"):
-                for sink in self._sinks:
-                    sink(info)
-        except BaseException:
-            for w, st in rollback:
-                w.restore_state(st)
-            raise                      # failed batches never enter the trace
-        self._commit(ranges, rec=rec)
-        self._batch_index += 1
-        self._history.append(info)
-        if self._delivery is not None:
-            # parallel lanes: enqueue only; check() surfaces a fail_pipeline
-            # lane's verdict (possibly from an earlier batch) and aborts here
-            with rec.stage("delivery_submit"):
-                self._delivery.submit(info)
-            self._delivery.check()
-        span = rec.finish(self._progress.epoch)
+        with TraceAnnotation("repro.batch", batch_index=info.index,
+                             num_records=info.num_records):
+            rec = self.traces.begin(self._batch_index, info.num_records)
+            rec.add("pump", pump_s)
+            per_topic: dict[str, list[OffsetRange]] = {}
+            for r in ranges:
+                per_topic.setdefault(r.topic, []).append(r)
+            # codec decode first (per-topic payload codecs are
+            # self-describing, see repro.data.codec), then the subscriber's
+            # own value_decoder
+            from repro.data.codec import compose_decoder
+            decoder = compose_decoder(self._decoder)
+            topic_rdds = [create_rdd(self.context, self.broker, rs, decoder)
+                          for rs in per_topic.values()]
+            union = (topic_rdds[0].union(*topic_rdds[1:])
+                     if len(topic_rdds) > 1 else topic_rdds[0])
+            # snapshot attached window state so a failed batch fn / serial
+            # sink rolls back cleanly: the replay must not find records
+            # half-pushed
+            rollback = [(w, w.state()) for _, w in self._window_states]
+            t0 = time.perf_counter()
+            try:
+                with rec.stage("batch_fn"):
+                    if self._batch_fn is not None:
+                        info.result = self._batch_fn(union, info)
+                info.processing_time = time.perf_counter() - t0
+                # Serial sinks run BEFORE the commit: a raising sink aborts
+                # the commit, so the batch (windower pushes included, via the
+                # rollback above) replays — the at-least-once contract the
+                # module docstring promises. Delivery lanes below keep their
+                # documented <= queue-depth post-commit crash window.
+                with rec.stage("sinks"):
+                    for sink in self._sinks:
+                        sink(info)
+            except BaseException:
+                for w, st in rollback:
+                    w.restore_state(st)
+                raise          # failed batches never enter the trace log
+            self._commit(ranges, rec=rec)
+            self._batch_index += 1
+            self._history.append(info)
+            if self._delivery is not None:
+                # parallel lanes: enqueue only; check() surfaces a
+                # fail_pipeline lane's verdict (possibly from an earlier
+                # batch) and aborts here
+                with rec.stage("delivery_submit"):
+                    self._delivery.submit(info)
+                self._delivery.check()
+            span = rec.finish(self._progress.epoch)
         self._m_batches.inc()
         self._m_records.inc(info.num_records)
         self._m_batch_s.observe(span.total_s)

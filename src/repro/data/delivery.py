@@ -63,6 +63,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Sequence
 
+from jax.profiler import TraceAnnotation
+
 from repro.utils import get_logger
 
 log = get_logger(__name__)
@@ -341,7 +343,13 @@ class SinkLane:
                 if self.policy.retry_backoff:
                     time.sleep(self.policy.retry_backoff)
             try:
-                self._write_once(payload)
+                # on the lane's thread: the batch's index ties the write to
+                # its repro.batch span on the pipeline's thread
+                with TraceAnnotation(
+                        "repro.lane.write", lane=self.name,
+                        batch_index=self._index_of(payload), attempt=attempt,
+                        queued_ms=1e3 * (time.perf_counter() - enqueued_at)):
+                    self._write_once(payload)
                 self.metrics.delivered += 1
                 self._m_delivered.inc()
                 lat = time.perf_counter() - enqueued_at

@@ -46,6 +46,11 @@ sinks, state commit, checkpoint, broker commit, delivery enqueue, each
 timed — tagged with the PR-5 checkpoint epoch, into a bounded in-memory
 log. A slow batch then decomposes into *which stage* ate the time
 (``GET /traces?last=N``), the per-chunk timing record DELTA writes to Mongo.
+The timer of each stage also opens a ``jax.profiler`` span named
+``repro.<stage>`` carrying ``batch_index``, so under a profiler the same
+intervals sit on the device's clock beside the ops they wait for
+(``docs/observability.md``, "Profiler spans and RAAR scopes"); with no
+profiler running a span costs about a microsecond.
 """
 from __future__ import annotations
 
@@ -55,6 +60,8 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable, Mapping
+
+from jax.profiler import TraceAnnotation
 
 # Fixed latency buckets (seconds): micro-batch and sink-write timings land
 # between ~0.5 ms and ~10 s on the paper's workloads.
@@ -470,7 +477,7 @@ class SpanRecorder:
         self._t0 = time.perf_counter()
 
     def stage(self, name: str) -> "_StageTimer":
-        return _StageTimer(self.span.stages, name)
+        return _StageTimer(self.span, name)
 
     def add(self, name: str, seconds: float) -> None:
         """Fold an externally-measured duration into a stage (accumulating)
@@ -486,17 +493,26 @@ class SpanRecorder:
 
 
 class _StageTimer:
-    def __init__(self, stages: dict[str, float], name: str) -> None:
-        self._stages = stages
+    """Times one stage into its span and, around the same interval, opens
+    the profiler span ``repro.<stage>`` with the batch's ``batch_index``:
+    the seconds recorded and the profiler's span cannot disagree."""
+
+    def __init__(self, span: BatchSpan, name: str) -> None:
+        self._span = span
         self._name = name
 
     def __enter__(self) -> "_StageTimer":
+        self._mark = TraceAnnotation(f"repro.{self._name}",
+                                     batch_index=self._span.batch_index)
+        self._mark.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc: Any) -> None:
         dt = time.perf_counter() - self._t0
-        self._stages[self._name] = self._stages.get(self._name, 0.0) + dt
+        self._mark.__exit__(*exc)
+        stages = self._span.stages
+        stages[self._name] = stages.get(self._name, 0.0) + dt
 
 
 class TraceLog:

@@ -1,5 +1,9 @@
 """Application-level behaviour: ptycho RAAR convergence, tomo ART, and the
 streaming pipelines end-to-end (paper §III/§IV)."""
+import os
+import re
+import subprocess
+import sys
 import threading
 import time
 
@@ -113,3 +117,20 @@ def test_near_realtime_pipeline_end_to_end():
     assert sum(sums) == sum(range(40))
     assert report.batches >= 4
     assert report.mean_latency < 0.5
+
+
+def test_ptycho_example_prints_each_batch_processing_time(tmp_path):
+    """The example's per-batch line reads the batch's processing time once
+    the stream has stamped it, not the 0.00 it holds inside the batch
+    function."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    out = subprocess.run(
+        [sys.executable, "examples/ptycho_pipeline.py", "--fast", "--out",
+         str(tmp_path / "out")], cwd=root, env=env, capture_output=True,
+        text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    procs = [float(t) for t in re.findall(r"^  batch \d+: .* proc ([\d.]+)s$",
+                                          out.stdout, re.M)]
+    assert len(procs) == 4 and min(procs) > 0, out.stdout

@@ -8,13 +8,16 @@ described ``v5e:2x2``. The topology is described inside a fixture, never at
 import: only one process may load the TPU library, and every test worker
 imports this file.
 """
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.apps.ptycho import solver
 from repro.apps.ptycho.solver import SolverConfig, raar_step
 from repro.kernels import dispatch
 from repro.kernels.art import kernel as art_kernel
@@ -102,3 +105,40 @@ def test_raar_step_compiles_with_pallas_forced(one_chip, monkeypatch):
     _assert_kernel(compiled, n=4)   # modulus, 2x overlap, combine
     peak = compiled.memory_analysis().peak_memory_in_bytes
     assert 0 < peak < 8 * 2**30, peak        # half of a v5e's 16 GB HBM
+
+
+def test_raar_phase_scopes_leave_the_tpu_program_unchanged(one_chip,
+                                                           monkeypatch):
+    """The RAAR step's phase scopes are metadata in the program the chip
+    runs too: with the kernels compiled and the iteration traced, the
+    program without them has the same ops, operands and fusions, and each
+    kernel call sits in the phase that owns it."""
+    monkeypatch.setattr(dispatch, "kernel_mode",
+                        lambda use_pallas=None: (True, False))
+    cfg = SolverConfig()
+    c64 = jnp.complex64
+    args = (_spec((FRAMES, FRAME, FRAME), one_chip, c64),
+            _spec((FRAMES, FRAME, FRAME), one_chip),
+            _spec((FRAMES, 2), one_chip, jnp.int32),
+            _spec((FRAME, FRAME), one_chip, c64),
+            _spec((), one_chip, jnp.int32))
+
+    def compiled_text():
+        step = jax.jit(lambda psi, mag, pos, probe, it: raar_step(
+            psi, mag, pos, probe, (OBJ, OBJ), cfg, it))
+        return step.lower(*args).compile().as_text()
+
+    def body(text):
+        text = text[re.search(r"^(%|ENTRY)", text, re.M).start():]
+        return re.sub(r", metadata=\{[^}]*\}", "", text)
+
+    scoped = compiled_text()
+    kernels = re.findall(r"%(modulus_project|overlap_products|raar_combine)"
+                         r"[.\d]* = .*op_name=\"[^\"]*/raar/(\w+)/", scoped)
+    assert sorted(kernels) == [("modulus_project", "modulus"),
+                               ("overlap_products", "object_solve"),
+                               ("overlap_products", "probe_solve"),
+                               ("raar_combine", "combine")]
+    monkeypatch.setattr(solver, "_phase",
+                        lambda name: contextlib.nullcontext())
+    assert body(compiled_text()) == body(scoped)
