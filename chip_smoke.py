@@ -11,8 +11,9 @@ at a time, so no phase starts a child that would need it.
 
   (a) device    fail unless JAX's first device is a TPU; kernels dispatch
                 compiled, never in interpret mode
-  (b) kernels   modulus, overlap, RAAR combine and ART sweep, compiled, at
-                the main-path shapes, against their ``ref.py``
+  (b) kernels   modulus, overlap products and scatter, RAAR combine and ART
+                sweep, compiled, at the main-path shapes, against their
+                ``ref.py``
   (c) ptycho    ``examples/ptycho_pipeline.py`` at the paper's Table II size
                 (512 frames of 64² streamed over a 256² object)
   (d) tomo      ``examples/tomo_pipeline.py`` at its defaults (64 rays,
@@ -102,6 +103,7 @@ def phase_kernels() -> str:
     from repro.apps.tomo.solver import TomoConfig, simulate_tilt_series
     from repro.kernels.art import kernel as art_k, ref as art_r
     from repro.kernels.modulus import kernel as mod_k, ref as mod_r
+    from repro.apps.ptycho.sim import scan_grid
     from repro.kernels.overlap import kernel as ov_k, ref as ov_r
     from repro.kernels.raar import kernel as raar_k, ref as raar_r
 
@@ -109,11 +111,19 @@ def phase_kernels() -> str:
     keys = jax.random.split(jax.random.PRNGKey(0), 8)
     planes = [jax.random.normal(k, shape, jnp.float32) for k in keys]
     mag = jnp.abs(planes[2])
+    pos = jnp.asarray(scan_grid(OBJ, FRAME, SCAN_STEP)[:FRAMES])
+    probe = jax.lax.complex(planes[2][0], planes[3][0])
+    num, den = ov_r.overlap_scatter_complex(
+        jax.lax.complex(*planes[:2]), probe, pos, (OBJ, OBJ))
     cases = {
         "modulus": (mod_k.modulus_project(*planes[:2], mag, interpret=False),
                     mod_r.modulus_project_ref(*planes[:2], mag)),
         "overlap": (ov_k.overlap_products(*planes[:4], interpret=False),
                     ov_r.overlap_products_ref(*planes[:4])),
+        "overlap scatter": (ov_k.overlap_scatter(
+            *planes[:2], planes[2][0], planes[3][0], pos,
+            obj_shape=(OBJ, OBJ), interpret=False),
+            (jnp.real(num), jnp.imag(num), den)),
         "raar": (raar_k.raar_combine(*planes, beta=0.75, interpret=False),
                  raar_r.raar_combine_ref(*planes, beta=0.75)),
     }

@@ -6,7 +6,9 @@ Per iteration (SHARP schedule — one overlap solve per iteration):
   2. overlap update (eqs. 4–5): new probe P and object O from ψ₁ — the
      partial sums Σψ_jO*, Σ|O|², Σψ_jP*, Σ|P|² are *framewise independent*,
      so frames shard across workers and the sums combine with
-     MPI_Allreduce ≡ ``jax.lax.psum`` (paper Fig. 9).       (Pallas products)
+     MPI_Allreduce ≡ ``jax.lax.psum`` (paper Fig. 9). The object sums are
+     placed on the canvas by one Pallas kernel (``overlap_scatter``); the
+     probe sums gather the object patches in XLA.   (Pallas scatter, products)
   3. π₂ψ₁ = P·O_patch  with the updated P, O.
   4. RAAR combine (eq. 7): ψ ← 2βπ₂π₁ψ + (1-2β)π₁ψ + β(ψ-π₂ψ)
      with π₂ψ ≈ π₂π₁ψ under the fixed-(P,O) projector — SHARP's
@@ -73,17 +75,17 @@ def overlap_update(psi: jax.Array, positions: jax.Array, probe: jax.Array,
                    ) -> tuple[jax.Array, jax.Array]:
     """Eqs. (4)–(5): closed-form O and P from exit waves ψ.
 
+    The object sums Σ_j ψ_j P* and Σ_j |P|², each frame's product placed at
+    its scan position on the ``obj_shape`` canvas, come from
+    ``overlap_ops.overlap_scatter`` (the Pallas kernel on a TPU, XLA's
+    scatter-add elsewhere). The probe sums gather the new object's patches.
     With ``axis_name``, partial sums are psum'd across the worker axis —
     the paper's MPI_Allreduce (Fig. 9)."""
-    F, h, w = psi.shape
     with _phase("object_solve"):
-        iy, ix = _patch_indices(positions, h)
         # object update: O = Σ ψ_j P* / Σ |P|²
-        num_o, den_o = overlap_ops.overlap_products(
-            psi, jnp.broadcast_to(probe[None], psi.shape),
-            use_pallas=use_pallas)
-        num = jnp.zeros(obj_shape, psi.dtype).at[iy, ix].add(num_o)
-        den = jnp.zeros(obj_shape, jnp.float32).at[iy, ix].add(den_o)
+        num, den = overlap_ops.overlap_scatter(psi, probe, positions,
+                                               obj_shape,
+                                               use_pallas=use_pallas)
         if axis_name:
             num = jax.lax.psum(num, axis_name)
             den = jax.lax.psum(den, axis_name)
@@ -93,6 +95,7 @@ def overlap_update(psi: jax.Array, positions: jax.Array, probe: jax.Array,
         return obj, probe
     with _phase("probe_solve"):
         # probe update: P = Σ ψ_j O*_patch / Σ |O_patch|²
+        iy, ix = _patch_indices(positions, psi.shape[-1])
         patches = obj[iy, ix]
         num_p, den_p = overlap_ops.overlap_products(psi, patches,
                                                     use_pallas=use_pallas)

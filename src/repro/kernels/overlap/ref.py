@@ -1,4 +1,4 @@
-"""Pure-jnp oracle for the overlap products kernel."""
+"""Pure-jnp oracles for the overlap kernels."""
 from __future__ import annotations
 
 import jax
@@ -16,3 +16,18 @@ def overlap_products_complex(a: jax.Array, b: jax.Array
                              ) -> tuple[jax.Array, jax.Array]:
     """(a · conj(b), |b|²)."""
     return a * jnp.conj(b), jnp.square(jnp.abs(b))
+
+
+def overlap_scatter_complex(psi: jax.Array, probe: jax.Array,
+                            positions: jax.Array, obj_shape: tuple[int, int]
+                            ) -> tuple[jax.Array, jax.Array]:
+    """(Σ_j place_j(ψ_j · conj(P)), Σ_j place_j(|P|²)) as XLA's per-pixel
+    scatter-add."""
+    rows = jnp.arange(psi.shape[-1])
+    iy = positions[:, 0, None, None] + rows[None, :, None]
+    ix = positions[:, 1, None, None] + rows[None, None, :]
+    num_o, den_o = overlap_products_complex(
+        psi, jnp.broadcast_to(probe[None], psi.shape))
+    num = jnp.zeros(obj_shape, psi.dtype).at[iy, ix].add(num_o)
+    den = jnp.zeros(obj_shape, jnp.float32).at[iy, ix].add(den_o)
+    return num, den

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.apps.ptycho.solver import overlap_update
 from repro.kernels import dispatch
 from repro.kernels.art import ops as art_ops
 from repro.kernels.art import ref as art_ref
@@ -99,6 +100,66 @@ def test_overlap_matches_complex_ref():
                                np.asarray(num_c), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(den), np.asarray(den_c),
                                rtol=1e-5, atol=1e-5)
+
+
+def _scatter_case(frames, n, obj, seed):
+    """Waves, probe and positions for ``overlap_scatter``: random positions
+    on the canvas plus the corners 0 and H-n, a row off the 8-row tiling
+    and a patch across the 128-lane boundary."""
+    rng = np.random.default_rng(seed)
+    a_re, a_im = rng.standard_normal((2, frames, n, n), np.float32)
+    p_re, p_im = rng.standard_normal((2, n, n), np.float32)
+    lim = obj - n
+    pos = rng.integers(0, lim + 1, (frames, 2))
+    edges = [(0, 0), (lim, lim), (lim, 0), (min(5, lim), min(120, lim))]
+    pos[:len(edges)] = edges[:frames]
+    return a_re, a_im, p_re, p_im, pos.astype(np.int32)
+
+
+def _scatter_oracle(a_re, a_im, p_re, p_im, pos, obj):
+    """float64 ``np.add.at`` of ψ·conj(P) and |P|² at each position."""
+    n = a_re.shape[-1]
+    p = p_re.astype(np.float64) + 1j * p_im
+    num_o = (a_re.astype(np.float64) + 1j * a_im) * np.conj(p)[None]
+    den_o = np.broadcast_to(np.abs(p) ** 2, num_o.shape)
+    iy = pos[:, 0, None, None] + np.arange(n)[None, :, None]
+    ix = pos[:, 1, None, None] + np.arange(n)[None, None, :]
+    num = np.zeros((obj, obj), np.complex128)
+    den = np.zeros((obj, obj))
+    np.add.at(num, (iy, ix), num_o)
+    np.add.at(den, (iy, ix), den_o)
+    return num.real, num.imag, den
+
+
+@pytest.mark.parametrize("frames,n,obj", [
+    (512, 64, 256),     # paper Table II
+    (16, 16, 48),       # the small size of the step tests
+    (20, 24, 64),       # 16-frame blocks: the second holds 4
+    (3, 8, 200),        # fewer frames than a block; canvas past 128 lanes
+])
+def test_overlap_scatter_sweep(frames, n, obj):
+    case = _scatter_case(frames, n, obj, seed=frames)
+    got = ov_kernel.overlap_scatter(*map(jnp.asarray, case),
+                                    obj_shape=(obj, obj), interpret=True)
+    for g, w in zip(got, _scatter_oracle(*case, obj)):
+        assert g.shape == (obj, obj)
+        np.testing.assert_allclose(np.asarray(g), w, rtol=1e-5,
+                                   atol=1e-6 * np.abs(w).max())
+
+
+def test_overlap_update_pallas_matches_xla_scatter():
+    """``overlap_update``'s object and probe with the Pallas scatter
+    (interpret) against XLA's ``.at[].add`` path."""
+    a_re, a_im, p_re, p_im, pos = _scatter_case(40, 16, 72, seed=7)
+    psi = jnp.asarray(a_re + 1j * a_im)
+    probe = jnp.asarray(p_re + 1j * p_im)
+    want = overlap_update(psi, jnp.asarray(pos), probe, (72, 72),
+                          use_pallas=False)
+    got = overlap_update(psi, jnp.asarray(pos), probe, (72, 72),
+                         use_pallas=True)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5)
 
 
 # -- art ----------------------------------------------------------------------

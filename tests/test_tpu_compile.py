@@ -2,11 +2,11 @@
 
 Interpret mode cannot see what the TPU's compiler refuses (block shapes off
 the (8, 128) tiling, too much VMEM), so every main-path kernel is compiled
-here at the paper's widths — 512 frames of 64² for ptychography, the
-default 64-ray, 25-angle ART system for tomography — for one chip of a
-described ``v5e:2x2``. The topology is described inside a fixture, never at
-import: only one process may load the TPU library, and every test worker
-imports this file.
+here at the paper's widths — 512 frames of 64² on a 256² object for
+ptychography, the default 64-ray, 25-angle ART system for tomography — for
+one chip of a described ``v5e:2x2``. The topology is described inside a
+fixture, never at import: only one process may load the TPU library, and
+every test worker imports this file.
 """
 import contextlib
 import os
@@ -102,9 +102,55 @@ def test_raar_step_compiles_with_pallas_forced(one_chip, monkeypatch):
         _spec((FRAMES, FRAME, FRAME), one_chip),
         _spec((FRAMES, 2), one_chip, jnp.int32),
         _spec((FRAME, FRAME), one_chip, c64)).compile()
-    _assert_kernel(compiled, n=4)   # modulus, 2x overlap, combine
+    _assert_kernel(compiled, n=4)   # modulus, overlap scatter and
+    #                                 products, combine
     peak = compiled.memory_analysis().peak_memory_in_bytes
     assert 0 < peak < 8 * 2**30, peak        # half of a v5e's 16 GB HBM
+
+
+def test_object_solve_runs_the_overlap_scatter_kernel(one_chip, monkeypatch):
+    """The Table II step, kernels forced, sums the object side in the
+    ``overlap_scatter`` kernel under ``raar/object_solve``, and XLA's
+    per-pixel scatter is gone from the program: no ``scatter`` instruction
+    is left in any phase, fusions included (the TPU compiler's scatter
+    rewrite drops an op's name, so the phase alone would not find one)."""
+    monkeypatch.setattr(dispatch, "kernel_mode",
+                        lambda use_pallas=None: (True, False))
+    cfg = SolverConfig()
+    c64 = jnp.complex64
+    text = jax.jit(lambda psi, mag, pos, probe, it: raar_step(
+        psi, mag, pos, probe, (OBJ, OBJ), cfg, it)).lower(
+        _spec((FRAMES, FRAME, FRAME), one_chip, c64),
+        _spec((FRAMES, FRAME, FRAME), one_chip),
+        _spec((FRAMES, 2), one_chip, jnp.int32),
+        _spec((FRAME, FRAME), one_chip, c64),
+        _spec((), one_chip, jnp.int32)).compile().as_text()
+    calls = re.findall(r"%overlap_scatter[.\d]* = .*custom-call\(.*"
+                       r"op_name=\"[^\"]*/raar/(\w+)/", text)
+    assert calls == ["object_solve"], calls
+    assert not re.findall(r".*\bscatter\(.*", text)
+
+
+@pytest.mark.parametrize("frames,n,obj,fits", [
+    (FRAMES, FRAME, (3200, 3072), True),    # the largest canvas for 64²
+    (64, 256, (1024, 1024), True),          # large frames, a raised limit
+    (FRAMES, FRAME, (3264, 3072), False),   # one step past the largest
+    (256, 128, (1024, 8192), False),
+])
+def test_overlap_scatter_vmem_ceiling(one_chip, frames, n, obj, fits):
+    """The scatter kernel compiles for a v5e up to the VMEM its estimate
+    allows, and past it refuses with ``ValueError`` before compiling."""
+    wave = _spec((frames, n, n), one_chip)
+    probe = _spec((n, n), one_chip)
+    args = (wave, wave, probe, probe, _spec((frames, 2), one_chip, jnp.int32))
+    need = ov_kernel.scatter_vmem_bytes(frames, n, obj)
+    assert (need <= ov_kernel.MAX_VMEM_BYTES) == fits, need
+    if fits:
+        _assert_kernel(ov_kernel.overlap_scatter.lower(
+            *args, obj_shape=obj).compile())
+    else:
+        with pytest.raises(ValueError, match="use_pallas=False"):
+            ov_kernel.overlap_scatter.lower(*args, obj_shape=obj)
 
 
 def test_raar_phase_scopes_leave_the_tpu_program_unchanged(one_chip,
@@ -133,11 +179,11 @@ def test_raar_phase_scopes_leave_the_tpu_program_unchanged(one_chip,
         return re.sub(r", metadata=\{[^}]*\}", "", text)
 
     scoped = compiled_text()
-    kernels = re.findall(r"%(modulus_project|overlap_products|raar_combine)"
+    kernels = re.findall(r"%(modulus_project|overlap_\w+|raar_combine)"
                          r"[.\d]* = .*op_name=\"[^\"]*/raar/(\w+)/", scoped)
     assert sorted(kernels) == [("modulus_project", "modulus"),
-                               ("overlap_products", "object_solve"),
                                ("overlap_products", "probe_solve"),
+                               ("overlap_scatter", "object_solve"),
                                ("raar_combine", "combine")]
     monkeypatch.setattr(solver, "_phase",
                         lambda name: contextlib.nullcontext())
