@@ -33,7 +33,7 @@ def tomviz_art(A: np.ndarray, b: np.ndarray, iters: int = 1,
 
 def run(nray: int = 32, angles: int = 19, nslice: int = 8) -> None:
     from repro.apps.tomo.projector import make_system
-    from repro.apps.tomo.solver import (TomoConfig, reconstruct_slices,
+    from repro.apps.tomo.solver import (SliceReconstructor, TomoConfig,
                                         simulate_tilt_series)
 
     cfg = TomoConfig(nray=nray,
@@ -46,8 +46,9 @@ def run(nray: int = 32, angles: int = 19, nslice: int = 8) -> None:
     emit("tomo/tomviz_numpy_slice", t_tomviz,
          f"measured: {angles * nray} rows x {nray}^2, pure numpy")
 
-    reconstruct_slices(sino[:1], cfg)  # compile
-    t_ours = time_call(lambda: reconstruct_slices(sino[:1], cfg), repeats=3)
+    operator = SliceReconstructor(cfg)
+    operator(sino[:1])  # compile
+    t_ours = time_call(lambda: operator(sino[:1]), repeats=3)
     emit("tomo/art_jax_slice", t_ours,
          f"measured: same slice, jitted ART; speedup x{t_tomviz / t_ours:.1f}"
          f" (paper claims 6x over TomViz)")
@@ -61,8 +62,8 @@ def run(nray: int = 32, angles: int = 19, nslice: int = 8) -> None:
 
         def job():
             rdd.map_partitions(
-                lambda items: reconstruct_slices(
-                    np.stack([b for _, b in items]), cfg)).collect_partitions()
+                lambda items: operator(
+                    np.stack([b for _, b in items]))).collect_partitions()
 
         t = time_call(job, repeats=2)
         # embarrassingly parallel on real hardware: derived = t1 / workers

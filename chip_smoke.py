@@ -17,7 +17,8 @@ at a time, so no phase starts a child that would need it.
   (c) ptycho    ``examples/ptycho_pipeline.py`` at the paper's Table II size
                 (512 frames of 64² streamed over a 256² object)
   (d) tomo      ``examples/tomo_pipeline.py`` at its defaults (64 rays,
-                25 angles, 32 slices, ART through the kernel)
+                25 angles, 32 slices, ART through the kernel), its system
+                matrix placed on the chip once for the whole stream
   (e) --chips 4 only: ``MPIBridge.allreduce`` of the paper's Table I
                 payload against a numpy sum, and ``raar_step`` with its
                 frames split over the chips (``psum`` of the overlap sums)
@@ -190,9 +191,12 @@ def phase_tomo() -> str:
     check(len(res["artifact_keys"]) > 0
           and all(os.path.exists(p) for p in res["renders"]),
           "missing sub-volume artifacts or renders")
+    check(res["system_placements"] == 1,
+          f"system matrix placed {res['system_placements']} times, not once")
     return (f"{res['slices']} slices in {res['batches']} micro-batches, "
             f"sinogram residual {r:.4f} (ceiling {TOMO_MAX_RESIDUAL:.3f}), "
-            f"volume rel err {res['volume_error']:.4f}")
+            f"volume rel err {res['volume_error']:.4f}, system matrix "
+            f"placed once")
 
 
 def phase_collectives(chips: int) -> str:

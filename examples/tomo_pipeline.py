@@ -24,7 +24,8 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.apps.tomo.render import render_volume
-from repro.apps.tomo.solver import (TomoConfig, reconstruct_slices, residual,
+from repro.apps.tomo.solver import (SliceReconstructor, TomoConfig,
+                                    reconstruct_batch, residual,
                                     simulate_tilt_series)
 from repro.core import Broker, Context, NearRealTimePipeline, PipelineConfig
 from repro.core.rdd import TaskScheduler
@@ -69,24 +70,11 @@ def main(argv: list[str] | None = None) -> dict:
     batch_slices = max(1, args.nslice // args.partitions)
 
     # steps 2+3 per micro-batch: repartition neighbouring slices, ART sweep
+    # against the system matrix the operator keeps on the device
+    operator = SliceReconstructor(cfg)
+
     def process(rdd, info, bridge):
-        records = sorted(rdd.collect())          # (i, row), scan order
-        if not records:
-            return None
-        part = ctx.parallelize(records, min(args.partitions, len(records)))
-
-        def art_sweep(items):
-            idx = [i for i, _ in items]
-            block = np.stack([b for _, b in items])
-            return idx, reconstruct_slices(block, cfg)
-
-        parts = part.map_partitions(art_sweep).collect_partitions()
-        out = []
-        for idx, block in parts:
-            key = f"slices-{idx[0]:04d}-{idx[-1]:04d}"
-            out.append((key, {"idx": np.asarray(idx, np.int64),
-                              "block": block}))
-        return out
+        return reconstruct_batch(rdd, operator, args.partitions)
 
     pipeline = NearRealTimePipeline(
         Broker(),
@@ -126,13 +114,16 @@ def main(argv: list[str] | None = None) -> dict:
           f"({rep['batches']} micro-batches, "
           f"{rep['throughput_rec_per_s']:.1f} slices/s)")
     print(f"sinogram residual {r:.3f}; volume rel. error {err:.3f}")
-    print(f"scheduler metrics: {ctx.scheduler.metrics}")
+    print(f"scheduler metrics: {ctx.scheduler.metrics}; system matrix "
+          f"placed on the device {operator.placements}x "
+          f"({operator.placed_bytes / 1e6:.1f} MB)")
     print(f"sub-volume artifacts: {sink.keys_on_disk()}")
     paths = render_volume(recon, args.out)
     print("artifacts:", paths)
     return {"residual": r, "volume_error": float(err),
             "batches": rep["batches"], "slices": args.nslice, "seconds": dt,
-            "artifact_keys": sink.keys_on_disk(), "renders": paths}
+            "artifact_keys": sink.keys_on_disk(), "renders": paths,
+            "system_placements": operator.placements}
 
 
 if __name__ == "__main__":

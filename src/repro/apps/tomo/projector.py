@@ -16,6 +16,9 @@ import numpy as np
 
 @functools.lru_cache(maxsize=8)
 def parallel_ray_matrix(nray: int, angles_key: tuple) -> np.ndarray:
+    """Rows of all ``nray`` rays of one angle at a time: each ray is sampled
+    at ``2·nray`` points, and each sample adds its four bilinear weights
+    (times the sample step) into the pixels around it."""
     angles = np.asarray(angles_key, dtype=np.float64)
     n = nray
     nsamp = 2 * n
@@ -23,26 +26,28 @@ def parallel_ray_matrix(nray: int, angles_key: tuple) -> np.ndarray:
     offs = np.arange(n) - n / 2 + 0.5
     A = np.zeros((len(angles) * n, n * n), dtype=np.float32)
     step = ts[1] - ts[0]
+    ray = np.repeat(np.arange(n), nsamp)              # ray of each sample
     for ai, theta in enumerate(np.deg2rad(angles)):
         d = np.array([np.cos(theta), np.sin(theta)])      # ray direction
         o = np.array([-np.sin(theta), np.cos(theta)])     # detector axis
-        for ri, r in enumerate(offs):
-            # sample points along the ray
-            pts = r * o[None, :] + ts[:, None] * d[None, :] + n / 2 - 0.5
-            ys, xs = pts[:, 0], pts[:, 1]
-            y0 = np.floor(ys).astype(int)
-            x0 = np.floor(xs).astype(int)
-            fy, fx = ys - y0, xs - x0
-            row = np.zeros(n * n, dtype=np.float32)
-            for dy, dx, wgt in ((0, 0, (1 - fy) * (1 - fx)),
-                                (0, 1, (1 - fy) * fx),
-                                (1, 0, fy * (1 - fx)),
-                                (1, 1, fy * fx)):
-                yy, xx = y0 + dy, x0 + dx
-                ok = (yy >= 0) & (yy < n) & (xx >= 0) & (xx < n)
-                np.add.at(row, (yy[ok] * n + xx[ok]),
-                          (wgt[ok] * step).astype(np.float32))
-            A[ai * n + ri] = row
+        # (ray, sample, yx): the sample points of every ray of this angle
+        pts = (offs[:, None, None] * o + ts[None, :, None] * d
+               + n / 2 - 0.5).reshape(-1, 2)
+        ys, xs = pts[:, 0], pts[:, 1]
+        y0 = np.floor(ys).astype(int)
+        x0 = np.floor(xs).astype(int)
+        fy, fx = ys - y0, xs - x0
+        block = A[ai * n:(ai + 1) * n]       # this angle's rows, a view
+        for dy, dx, wgt in ((0, 0, (1 - fy) * (1 - fx)),
+                            (0, 1, (1 - fy) * fx),
+                            (1, 0, fy * (1 - fx)),
+                            (1, 1, fy * fx)):
+            yy, xx = y0 + dy, x0 + dx
+            ok = (yy >= 0) & (yy < n) & (xx >= 0) & (xx < n)
+            # float32 adds in the per-ray order: each row sums its samples
+            # corner by corner, as a ray-at-a-time loop does
+            np.add.at(block, (ray[ok], yy[ok] * n + xx[ok]),
+                      (wgt[ok] * step).astype(np.float32))
     return A
 
 

@@ -9,13 +9,14 @@ rendering stage (apps/tomo/render.py — the ParaView stage of Fig. 11).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.apps.tomo.projector import make_system, project
+from repro.core.rdd import RDD
+from repro.data.metrics import get_registry
 from repro.kernels.art import ops as art_ops
 
 
@@ -56,33 +57,67 @@ def simulate_tilt_series(config: TomoConfig, nslice: int,
     return vol, sino.astype(np.float32)
 
 
-import functools
+class SliceReconstructor:
+    """The ART operator of one :class:`TomoConfig`: the dense system matrix
+    ``A`` and its ``1/‖A_j‖²`` are placed on the device once, when the
+    operator is made, and every call reconstructs a block of slices with
+    the compiled sweep against them. A worker holds one operator for its
+    whole stream, so no batch copies ``A`` again.
+
+    ``placements`` and ``placed_bytes`` count the copies of ``A`` to the
+    device (also as ``tomo_system_placements_total`` and
+    ``tomo_system_bytes_total`` in the metrics registry)."""
+
+    def __init__(self, config: TomoConfig) -> None:
+        self.config = config
+        n = config.nray
+        A = make_system(n, np.asarray(config.angles))
+        self.A = jax.device_put(A)
+        self.inv_rip = jax.jit(art_ops.inverse_row_norms)(self.A)
+        self.placements, self.placed_bytes = 1, A.nbytes
+        reg = get_registry()
+        reg.counter("tomo_system_placements_total",
+                    help="copies of an ART system matrix to the device").inc()
+        reg.counter("tomo_system_bytes_total",
+                    help="bytes of ART system matrices copied to the "
+                         "device").inc(A.nbytes)
+
+        def run(A, inv_rip, blocks):
+            with jax.named_scope("art/sweep"):
+                f = art_ops.art_sweep_slices(
+                    A, blocks, inv_rip, beta=config.beta,
+                    iters=config.iterations, use_pallas=config.use_pallas)
+            return f.reshape(-1, n, n)
+
+        self._run = jax.jit(run)
+
+    def __call__(self, sino_slices: np.ndarray) -> np.ndarray:
+        """ART-reconstruct a block of slices (one RDD partition's work):
+        (k, Nrow) -> (k, Nray, Nray)."""
+        blocks = jnp.asarray(sino_slices, jnp.float32)
+        return np.asarray(self._run(self.A, self.inv_rip, blocks))
 
 
-@functools.lru_cache(maxsize=8)
-def _slice_reconstructor(config: TomoConfig):
-    """Jitted per-config slice solver (cached — compile once)."""
-    n = config.nray
+def reconstruct_batch(rdd: RDD, operator: SliceReconstructor,
+                      partitions: int) -> list[tuple[str, dict]] | None:
+    """One micro-batch of the tilt series, as the paper's Fig. 11 runs it:
+    the batch's ``(slice, row)`` records in slice order, ``parallelize``d
+    into ``partitions`` of neighbouring slices, the operator mapped over
+    each partition by the RDD scheduler, and one keyed sub-volume per
+    partition (``slices-<first>-<last>``: the slice indices and their
+    reconstruction). None for an empty batch."""
+    records = sorted(rdd.collect(), key=lambda rec: rec[0])
+    if not records:
+        return None
+    part = rdd.context.parallelize(records, min(partitions, len(records)))
 
-    def run(A, blocks):
-        def one(b):
-            f = art_ops.art_reconstruct_slice(
-                A, b, jnp.zeros((n * n,), jnp.float32), beta=config.beta,
-                iters=config.iterations, use_pallas=config.use_pallas)
-            return f.reshape(n, n)
-        return jax.vmap(one)(blocks)
+    def sweep(items):
+        idx = [i for i, _ in items]
+        return idx, operator(np.stack([row for _, row in items]))
 
-    return jax.jit(run)
-
-
-def reconstruct_slices(sino_slices: np.ndarray, config: TomoConfig
-                       ) -> np.ndarray:
-    """ART-reconstruct a block of slices (one RDD partition's work).
-
-    sino_slices: (k, Nrow) -> (k, Nray, Nray)."""
-    A = jnp.asarray(make_system(config.nray, np.asarray(config.angles)))
-    out = _slice_reconstructor(config)(A, jnp.asarray(sino_slices))
-    return np.asarray(out)
+    return [(f"slices-{idx[0]:04d}-{idx[-1]:04d}",
+             {"idx": np.asarray(idx, np.int64), "block": block})
+            for idx, block in part.map_partitions(sweep).collect_partitions()]
 
 
 def residual(volume: np.ndarray, sino: np.ndarray,

@@ -31,6 +31,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Iterable, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.utils import get_logger
 
@@ -256,12 +257,24 @@ class TaskScheduler:
                         "speculative_wins": 0}
 
     def _run_task(self, rdd: RDD, attempt: TaskAttempt) -> Any:
-        self.metrics["tasks"] += 1
-        if self.failure_injector is not None:
-            self.failure_injector.on_task(attempt)
-        return rdd.compute_partition(attempt.partition)
+        with TraceAnnotation("repro.rdd.task", rdd=attempt.rdd_id,
+                             partition=attempt.partition,
+                             attempt=attempt.attempt,
+                             speculative=attempt.speculative):
+            self.metrics["tasks"] += 1
+            if self.failure_injector is not None:
+                self.failure_injector.on_task(attempt)
+            return rdd.compute_partition(attempt.partition)
 
     def run(self, rdd: RDD) -> list[Any]:
+        """Every partition of ``rdd``, in order, under one profiler span
+        ``repro.rdd.job``; each task attempt opens ``repro.rdd.task`` on the
+        executor thread that runs it."""
+        with TraceAnnotation("repro.rdd.job", rdd=rdd.id,
+                             partitions=rdd.num_partitions):
+            return self._run(rdd)
+
+    def _run(self, rdd: RDD) -> list[Any]:
         n = rdd.num_partitions
         results: dict[int, Any] = {}
         attempts: dict[int, int] = {p: 0 for p in range(n)}

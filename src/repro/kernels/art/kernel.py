@@ -73,6 +73,7 @@ def art_sweep(A: jax.Array, b: jax.Array, inv_rip: jax.Array,
         out_specs=pl.BlockSpec((1, ncol), lambda i, j: (0, 0)),  # resident
         out_shape=jax.ShapeDtypeStruct((1, ncol), jnp.float32),
         interpret=interpret,
+        name="art_sweep",          # the kernel's op name in a device trace
     )(A, b.reshape(-1, 1).astype(jnp.float32),
       inv_rip.reshape(-1, 1).astype(jnp.float32),
       f0.reshape(1, ncol).astype(jnp.float32))

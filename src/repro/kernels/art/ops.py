@@ -1,4 +1,5 @@
-"""Jit'd wrapper for the ART sweep (platform dispatch + row-norm precompute)."""
+"""The ART sweep over a block of slices (platform dispatch) and its row-norm
+precompute."""
 from __future__ import annotations
 
 import jax
@@ -8,14 +9,26 @@ from repro.kernels import dispatch
 from repro.kernels.art import kernel, ref
 
 
-def art_reconstruct_slice(A: jax.Array, b: jax.Array, f0: jax.Array,
-                          beta: float = 1.0, iters: int = 1,
-                          use_pallas: bool | None = None) -> jax.Array:
-    """One tilt-series slice: A (Nrow, Ncol), b (Nrow,), f0 (Ncol,)."""
-    use_pallas, interpret = dispatch.kernel_mode(use_pallas)
+def inverse_row_norms(A: jax.Array) -> jax.Array:
+    """``1/‖A_j‖²`` of each row, 0 for an empty row (a no-op in the sweep)."""
     rip = jnp.sum(A * A, axis=1)
-    inv_rip = jnp.where(rip > 0, 1.0 / jnp.maximum(rip, 1e-12), 0.0)
+    return jnp.where(rip > 0, 1.0 / jnp.maximum(rip, 1e-12), 0.0)
+
+
+def art_sweep_slices(A: jax.Array, B: jax.Array, inv_rip: jax.Array,
+                     beta: float = 1.0, iters: int = 1,
+                     use_pallas: bool | None = None) -> jax.Array:
+    """Slices ``B`` (k, Nrow) from ``f0 = 0`` -> (k, Ncol), one system
+    ``A`` (Nrow, Ncol) with its ``inv_rip`` shared by every slice."""
+    use_pallas, interpret = dispatch.kernel_mode(use_pallas)
+    f0 = jnp.zeros((A.shape[1],), jnp.float32)
     if use_pallas:
-        return kernel.art_sweep(A, b, inv_rip, f0, beta=beta, iters=iters,
-                                interpret=interpret)
-    return ref.art_sweep_ref(A, b, inv_rip, f0, beta=beta, iters=iters)
+        def one(b):
+            return kernel.art_sweep(A, b, inv_rip, f0, beta=beta,
+                                    iters=iters, interpret=interpret)
+    else:
+        def one(b):
+            return ref.art_sweep_ref(A, b, inv_rip, f0, beta=beta,
+                                     iters=iters)
+    return jax.vmap(one)(B)
+

@@ -17,7 +17,7 @@ from repro.apps.ptycho.sim import (gather_patches, scatter_add_patches,
 from repro.apps.ptycho.solver import (SolverConfig, overlap_update,
                                       raar_step, reconstruct,
                                       reconstruction_quality, init_waves)
-from repro.apps.tomo.solver import (TomoConfig, reconstruct_slices, residual,
+from repro.apps.tomo.solver import (SliceReconstructor, TomoConfig, residual,
                                     simulate_tilt_series)
 from repro.core import (Broker, Context, NearRealTimePipeline,
                         PipelineConfig)
@@ -82,7 +82,7 @@ def test_tomo_art_reduces_residual():
     cfg = TomoConfig(nray=32, angles=tuple(np.linspace(-75, 75, 19).tolist()),
                      iterations=3, use_pallas=False)
     vol, sino = simulate_tilt_series(cfg, nslice=6)
-    rec = reconstruct_slices(sino, cfg)
+    rec = SliceReconstructor(cfg)(sino)
     r = residual(rec, sino, cfg)
     assert r < 0.3, r                      # limited-angle ART: large drop
     err = np.linalg.norm(rec - vol) / np.linalg.norm(vol)

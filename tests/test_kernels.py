@@ -189,8 +189,8 @@ def test_art_converges_consistent_system():
     A = jax.random.normal(key, (64, 16))
     f_true = jax.random.normal(jax.random.PRNGKey(7), (16,))
     b = A @ f_true
-    f = art_ops.art_reconstruct_slice(A, b, jnp.zeros((16,)), beta=1.0,
-                                      iters=30, use_pallas=True)
+    [f] = art_ops.art_sweep_slices(A, b[None], art_ops.inverse_row_norms(A),
+                                   beta=1.0, iters=30, use_pallas=True)
     np.testing.assert_allclose(np.asarray(f), np.asarray(f_true),
                                rtol=1e-3, atol=1e-3)
 

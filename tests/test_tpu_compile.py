@@ -28,6 +28,7 @@ from repro.kernels.raar import kernel as raar_kernel
 FRAMES, FRAME = 512, 64          # paper Table II
 OBJ = 256
 NRAY, ANGLES, SLICES = 64, 25, 32  # examples/tomo_pipeline.py defaults
+CELL_NRAY, CELL_SLICES = 256, 16   # tomo-art256: 16 slices per partition
 
 
 @pytest.fixture(scope="module")
@@ -69,11 +70,20 @@ def test_elementwise_kernel_compiles(one_chip, name, fn, n_in):
     _assert_kernel(fn.lower(*[x] * n_in).compile())
 
 
-@pytest.mark.parametrize("nrow", [ANGLES * NRAY, ANGLES * NRAY + 3])
-def test_art_sweep_compiles_under_vmap(one_chip, nrow):
+@pytest.mark.parametrize("nray,nrow,slices", [
+    pytest.param(NRAY, ANGLES * NRAY, SLICES, id=str(ANGLES * NRAY)),
+    pytest.param(NRAY, ANGLES * NRAY + 3, SLICES, id=str(ANGLES * NRAY + 3)),
+    # the tomo-art256 cell: 6400 x 65536 (1.68 GB), one partition's 16 slices
+    pytest.param(CELL_NRAY, ANGLES * CELL_NRAY, CELL_SLICES,
+                 id=str(ANGLES * CELL_NRAY)),
+])
+def test_art_sweep_compiles_under_vmap(one_chip, nray, nrow, slices):
     """(8, Ncol) row blocks, batched over slices as the tomography solver
-    runs them; the second case pads its rows to a multiple of 8."""
-    ncol = NRAY * NRAY
+    runs them; the second case pads its rows to a multiple of 8. At 256
+    rays a row block is (8, 65536) and the resident image (1, 65536): the
+    case pins the kernel's VMEM fit, and the matrix stays one operand,
+    not one copy per slice."""
+    ncol = nray * nray
 
     def sweep(A, B, inv_rip):
         f0 = jnp.zeros((ncol,), jnp.float32)
@@ -81,9 +91,12 @@ def test_art_sweep_compiles_under_vmap(one_chip, nrow):
             A, b, inv_rip, f0, beta=1.0, iters=2))(B)
 
     compiled = jax.jit(sweep).lower(
-        _spec((nrow, ncol), one_chip), _spec((SLICES, nrow), one_chip),
+        _spec((nrow, ncol), one_chip), _spec((slices, nrow), one_chip),
         _spec((nrow,), one_chip)).compile()
     _assert_kernel(compiled)
+    # no copy of A beyond the argument itself: the temporaries stay under
+    # one more A
+    assert compiled.memory_analysis().temp_size_in_bytes < nrow * ncol
 
 
 def test_raar_step_compiles_with_pallas_forced(one_chip, monkeypatch):
