@@ -8,6 +8,7 @@ rendering stage (apps/tomo/render.py — the ParaView stage of Fig. 11).
 """
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass
 
 import jax
@@ -17,6 +18,7 @@ import numpy as np
 from repro.apps.tomo.projector import make_system, project
 from repro.core.rdd import RDD
 from repro.data.metrics import get_registry
+from repro.kernels.art import kernel as art_kernel
 from repro.kernels.art import ops as art_ops
 
 
@@ -66,7 +68,11 @@ class SliceReconstructor:
 
     ``placements`` and ``placed_bytes`` count the copies of ``A`` to the
     device (also as ``tomo_system_placements_total`` and
-    ``tomo_system_bytes_total`` in the metrics registry)."""
+    ``tomo_system_bytes_total`` in the metrics registry). ``matrix_passes``
+    counts the passes of ``A`` the calls launched, ``sweeps × ⌈k / k_blk⌉``
+    for a block of k slices (also as ``tomo_matrix_passes_total``); each
+    call runs inside a ``repro.tomo.sweep`` profiler span with its
+    ``slices`` and ``passes``."""
 
     def __init__(self, config: TomoConfig) -> None:
         self.config = config
@@ -81,6 +87,11 @@ class SliceReconstructor:
         reg.counter("tomo_system_bytes_total",
                     help="bytes of ART system matrices copied to the "
                          "device").inc(A.nbytes)
+        self.matrix_passes = 0
+        self._passes = reg.counter(
+            "tomo_matrix_passes_total",
+            help="passes of an ART system matrix the sweeps launched")
+        self._lock = threading.Lock()      # partitions call on many threads
 
         def run(A, inv_rip, blocks):
             with jax.named_scope("art/sweep"):
@@ -94,8 +105,16 @@ class SliceReconstructor:
     def __call__(self, sino_slices: np.ndarray) -> np.ndarray:
         """ART-reconstruct a block of slices (one RDD partition's work):
         (k, Nrow) -> (k, Nray, Nray)."""
-        blocks = jnp.asarray(sino_slices, jnp.float32)
-        return np.asarray(self._run(self.A, self.inv_rip, blocks))
+        k = len(sino_slices)
+        passes = art_kernel.matrix_passes(k, self.config.iterations)
+        with jax.profiler.TraceAnnotation("repro.tomo.sweep", slices=k,
+                                          passes=passes):
+            blocks = jnp.asarray(sino_slices, jnp.float32)
+            out = np.asarray(self._run(self.A, self.inv_rip, blocks))
+        with self._lock:
+            self.matrix_passes += passes
+        self._passes.inc(passes)
+        return out
 
 
 def reconstruct_batch(rdd: RDD, operator: SliceReconstructor,

@@ -19,16 +19,13 @@ def art_sweep_slices(A: jax.Array, B: jax.Array, inv_rip: jax.Array,
                      beta: float = 1.0, iters: int = 1,
                      use_pallas: bool | None = None) -> jax.Array:
     """Slices ``B`` (k, Nrow) from ``f0 = 0`` -> (k, Ncol), one system
-    ``A`` (Nrow, Ncol) with its ``inv_rip`` shared by every slice."""
+    ``A`` (Nrow, Ncol) with its ``inv_rip`` shared by every slice. The
+    kernel sweeps the slices together (``kernel.matrix_passes`` passes of
+    ``A``); the reference sweeps each one alone."""
     use_pallas, interpret = dispatch.kernel_mode(use_pallas)
-    f0 = jnp.zeros((A.shape[1],), jnp.float32)
     if use_pallas:
-        def one(b):
-            return kernel.art_sweep(A, b, inv_rip, f0, beta=beta,
-                                    iters=iters, interpret=interpret)
-    else:
-        def one(b):
-            return ref.art_sweep_ref(A, b, inv_rip, f0, beta=beta,
-                                     iters=iters)
-    return jax.vmap(one)(B)
-
+        return kernel.art_sweep(A, B, inv_rip, beta=beta, iters=iters,
+                                interpret=interpret)
+    f0 = jnp.zeros((A.shape[1],), jnp.float32)
+    return jax.vmap(lambda b: ref.art_sweep_ref(A, b, inv_rip, f0, beta=beta,
+                                                iters=iters))(B)

@@ -3,6 +3,10 @@ from __future__ import annotations
 
 import jax
 
+# A v5e's compiler gives a Pallas kernel 16 MiB of its 128 MiB of VMEM
+# unless the kernel asks for more (``vmem_limit_bytes``).
+DEFAULT_VMEM_BYTES = 16 * 2**20
+
 
 def kernel_mode(use_pallas: bool | None = None) -> tuple[bool, bool]:
     """``(run the Pallas kernel, run it in interpret mode)``.

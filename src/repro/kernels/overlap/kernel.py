@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import dispatch
+
 
 def _overlap_kernel(a_re, a_im, b_re, b_im, n_re, n_im, den):
     bre = b_re[...]
@@ -57,10 +59,8 @@ def overlap_products(a_re, a_im, b_re, b_im, block_frames: int = 16,
     )(a_re, a_im, b_re, b_im)
 
 
-# A v5e has 128 MiB of VMEM. Its compiler gives a kernel 16 MiB unless the
-# kernel asks for more; this one asks for what its canvases need, up to
-# 120 MiB, and leaves the rest to XLA.
-DEFAULT_VMEM_BYTES = 16 * 2**20
+# Past the compiler's default (``dispatch.DEFAULT_VMEM_BYTES``) this kernel
+# asks for what its canvases need, up to 120 MiB, and leaves the rest to XLA.
 MAX_VMEM_BYTES = 120 * 2**20
 SCATTER_BLOCK = 16     # frames per grid step of ``overlap_scatter``
 
@@ -163,7 +163,8 @@ def overlap_scatter(a_re, a_im, p_re, p_im, positions,
         out_shape=[jax.ShapeDtypeStruct(canvas, jnp.float32)] * 3,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=None if need <= DEFAULT_VMEM_BYTES else need),
+            vmem_limit_bytes=None if need <= dispatch.DEFAULT_VMEM_BYTES
+            else need),
         interpret=interpret,
     )(positions.astype(jnp.int32).reshape(-1), a_re, a_im, p_re, p_im)
     return n_re[:H, :W], n_im[:H, :W], den[:H, :W]

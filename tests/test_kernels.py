@@ -163,24 +163,48 @@ def test_overlap_update_pallas_matches_xla_scatter():
 
 
 # -- art ----------------------------------------------------------------------
+def _art_system(nrow, ncol, k):
+    """A random system, the measurements of ``k`` random images through it,
+    and ``1/‖A_j‖²``."""
+    A = jax.random.normal(jax.random.PRNGKey(4), (nrow, ncol))
+    f_true = jax.random.normal(jax.random.PRNGKey(5), (k, ncol))
+    return A, f_true @ A.T, 1.0 / jnp.sum(A * A, axis=1)
+
+
 @pytest.mark.parametrize("nrow,ncol", [(8, 16), (20, 12), (32, 64),
                                        (13, 128)])
 @pytest.mark.parametrize("iters", [1, 3])
-def test_art_sweep(nrow, ncol, iters):
-    """Rows stream in blocks of 8; 20 and 13 rows pin the padding."""
-    key = jax.random.PRNGKey(4)
-    A = jax.random.normal(key, (nrow, ncol))
-    f_true = jax.random.normal(jax.random.PRNGKey(5), (ncol,))
-    b = A @ f_true
-    rip = jnp.sum(A * A, axis=1)
-    inv_rip = 1.0 / rip
-    f0 = jnp.zeros((ncol,))
+@pytest.mark.parametrize("k", [1, 3, 16, 20])
+def test_art_sweep(nrow, ncol, iters, k):
+    """Rows stream in blocks of 8; 20 and 13 rows pin the padding. Slices
+    are swept together in blocks of up to 16: one slice and 3 pad the
+    block to 8, 16 fill one block, 20 add a part-filled second block. Each
+    slice matches the reference run on it alone."""
     from repro.kernels.art import kernel as art_kernel
-    got = art_kernel.art_sweep(A, b, inv_rip, f0, beta=1.0, iters=iters,
+    A, B, inv_rip = _art_system(nrow, ncol, k)
+    got = art_kernel.art_sweep(A, B, inv_rip, beta=1.0, iters=iters,
                                interpret=True)
-    want = art_ref.art_sweep_ref(A, b, inv_rip, f0, beta=1.0, iters=iters)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
+    assert got.shape == (k, ncol)
+    alone = jax.jit(lambda b: art_ref.art_sweep_ref(
+        A, b, inv_rip, jnp.zeros((ncol,)), beta=1.0, iters=iters))
+    for b, g in zip(B, got):
+        want = alone(b)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(want),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_art_sweep_slice_ignores_its_block_mates():
+    """A slice's image is the same, to the bit, alone or in a block with 15
+    or 19 other slices: the slices share rows of ``A`` but no arithmetic."""
+    from repro.kernels.art import kernel as art_kernel
+    A, B, inv_rip = _art_system(20, 256, 20)
+    alone = art_kernel.art_sweep(A, B[3:4], inv_rip, iters=2, interpret=True)
+    for block in (B[:16], B, B[::-1]):
+        got = art_kernel.art_sweep(A, block, inv_rip, iters=2,
+                                   interpret=True)
+        row = [i for i, b in enumerate(block) if bool(jnp.all(b == B[3]))]
+        np.testing.assert_array_equal(np.asarray(got[row[0]]),
+                                      np.asarray(alone[0]))
 
 
 def test_art_converges_consistent_system():

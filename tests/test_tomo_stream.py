@@ -10,6 +10,10 @@ row at a time, in the kernel's row order, from ``f0 = 0``, with no JAX.
 The vectorised system matrix is compared with the ray-at-a-time loop it
 replaced.
 """
+import glob
+import os
+
+import jax
 import numpy as np
 import pytest
 
@@ -199,6 +203,38 @@ def test_several_batches_place_the_system_once(series, tmp_path):
     snap = {m.name: m.value() for m in registry.metrics()}
     assert snap["tomo_system_placements_total"] == 1
     assert snap["tomo_system_bytes_total"] == operator.placed_bytes
+
+
+def test_each_partition_sweep_is_counted_and_spanned(series, tmp_path):
+    """Each partition's call makes ``sweeps × ⌈k / k_blk⌉`` passes of
+    ``A``: here 2 slices in one block, 2 sweeps. The operator, the
+    registry's ``tomo_matrix_passes_total`` and the ``repro.tomo.sweep``
+    spans of 3 streamed batches all count them."""
+    registry = MetricsRegistry()
+    previous = set_registry(registry)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=opts)
+    try:
+        _, keys, operator = _stream(_config(None), series[0][0],
+                                    tmp_path / "sink")
+    finally:
+        jax.profiler.stop_trace()
+        set_registry(previous)
+    per = BATCH // PARTITIONS
+    assert len(keys) == 3 and all(len(k) == PARTITIONS for k in keys)
+    calls = 3 * PARTITIONS
+    assert operator.matrix_passes == calls * 2
+    snap = {m.name: m.value() for m in registry.metrics()}
+    assert snap["tomo_matrix_passes_total"] == calls * 2
+    [path] = glob.glob(os.path.join(tmp_path, "trace", "**", "*.xplane.pb"),
+                       recursive=True)
+    spans = [dict(ev.stats)
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if ev.name == "repro.tomo.sweep"]
+    assert [(int(s["slices"]), int(s["passes"])) for s in spans] == \
+        [(per, 2)] * calls
 
 
 def test_the_sweep_is_named_for_the_trace():

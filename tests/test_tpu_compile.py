@@ -55,6 +55,19 @@ def _spec(shape, sharding, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _pallas_grids(jaxpr) -> list[tuple[int, ...]]:
+    """The grid of each ``pallas_call`` in ``jaxpr``, nested jaxprs too."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(eqn.params["grid_mapping"].grid)
+        for p in eqn.params.values():
+            inner = getattr(p, "jaxpr", None)
+            if inner is not None:
+                grids += _pallas_grids(getattr(inner, "jaxpr", inner))
+    return grids
+
+
 def _assert_kernel(compiled, n: int = 1) -> None:
     got = compiled.as_text().count("tpu_custom_call")
     assert got >= n, f"{got} tpu_custom_call(s) in the compiled program"
@@ -76,27 +89,35 @@ def test_elementwise_kernel_compiles(one_chip, name, fn, n_in):
     # the tomo-art256 cell: 6400 x 65536 (1.68 GB), one partition's 16 slices
     pytest.param(CELL_NRAY, ANGLES * CELL_NRAY, CELL_SLICES,
                  id=str(ANGLES * CELL_NRAY)),
+    # 512 rays: the (16, 262144) images pass the compiler's default scoped
+    # VMEM, so the kernel asks for what it needs
+    pytest.param(2 * CELL_NRAY, ANGLES * 2 * CELL_NRAY, CELL_SLICES,
+                 id=str(ANGLES * 2 * CELL_NRAY)),
 ])
-def test_art_sweep_compiles_under_vmap(one_chip, nray, nrow, slices):
-    """(8, Ncol) row blocks, batched over slices as the tomography solver
-    runs them; the second case pads its rows to a multiple of 8. At 256
-    rays a row block is (8, 65536) and the resident image (1, 65536): the
-    case pins the kernel's VMEM fit, and the matrix stays one operand,
-    not one copy per slice."""
-    ncol = nray * nray
+def test_art_sweep_compiles_batched(one_chip, nray, nrow, slices):
+    """(8, Ncol) row blocks, the slices swept together as the tomography
+    solver runs them; the second case pads its rows to a multiple of 8. At
+    256 rays a row block is (8, 65536) and the resident images (16, 65536):
+    the case pins the kernel's VMEM fit, and the matrix stays one operand,
+    not one copy per slice; at 512 rays the images need more VMEM than the
+    compiler's default. The grid makes ``matrix_passes`` passes of
+    ``A``: one per sweep for the cell's 16 slices, two for the example's
+    32."""
+    ncol, sweeps = nray * nray, 2
 
     def sweep(A, B, inv_rip):
-        f0 = jnp.zeros((ncol,), jnp.float32)
-        return jax.vmap(lambda b: art_kernel.art_sweep(
-            A, b, inv_rip, f0, beta=1.0, iters=2))(B)
+        return art_kernel.art_sweep(A, B, inv_rip, beta=1.0, iters=sweeps)
 
-    compiled = jax.jit(sweep).lower(
-        _spec((nrow, ncol), one_chip), _spec((slices, nrow), one_chip),
-        _spec((nrow,), one_chip)).compile()
+    args = (_spec((nrow, ncol), one_chip), _spec((slices, nrow), one_chip),
+            _spec((nrow,), one_chip))
+    compiled = jax.jit(sweep).lower(*args).compile()
     _assert_kernel(compiled)
     # no copy of A beyond the argument itself: the temporaries stay under
     # one more A
     assert compiled.memory_analysis().temp_size_in_bytes < nrow * ncol
+    [grid] = _pallas_grids(jax.make_jaxpr(sweep)(*args).jaxpr)
+    passes = art_kernel.matrix_passes(slices, sweeps)
+    assert grid[0] * grid[1] == passes == sweeps * -(-slices // 16)
 
 
 def test_raar_step_compiles_with_pallas_forced(one_chip, monkeypatch):
