@@ -5,7 +5,7 @@
 PYTHON ?= python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 
-.PHONY: test test-data test-delivery test-state test-transport test-obs test-groups test-replication test-codec test-analyze analyze lint bench bench-check examples deps-check
+.PHONY: test test-data test-delivery test-state test-transport test-obs test-groups test-replication test-codec test-analyze analyze lint examples deps-check
 
 test:           ## tier-1: invariant analyzer first, then the full suite, stop at first failure
 	$(PYTHON) -m tools.analyze src/ tests/
@@ -48,12 +48,6 @@ test-replication: ## broker HA: follower replication, failover promotion, epoch 
 
 test-codec:     ## per-topic payload codecs: int8/zlib roundtrips, wire refusal, parity matrix
 	$(PYTHON) -m pytest -q tests/test_codec.py tests/test_broker_parity.py
-
-bench:          ## CSV benchmark sweep (includes bench_ingest)
-	$(PYTHON) -m benchmarks.run
-
-bench-check:    ## guards: produce_many >= 3x per-record, fan-out >= 2x serial, durable window state <= 1.3x in-memory, metrics registry <= 1.1x registry-off, replicated produce <= 1.3x unreplicated, shm frames >= 5x 'A'-frames, int8 codec >= 2x raw on a throttled link
-	$(PYTHON) -m benchmarks.run --check
 
 examples:       ## fast end-to-end example runs
 	$(PYTHON) examples/ptycho_pipeline.py --fast

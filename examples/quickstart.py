@@ -3,9 +3,8 @@
 Builds an RDD of per-worker arrays, then reduces it two ways:
   1. the Spark driver-worker path  (collect to driver, sum on driver);
   2. the Spark-MPI path            (in-place allreduce on the worker mesh).
-Both give the same numbers; Table I of the paper (and
-benchmarks/bench_allreduce.py here) quantifies why path 2 wins by ~100x on
-a real fabric.
+Both give the same numbers; Table I of the paper measures why path 2 wins
+on a real fabric.
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
@@ -38,7 +37,7 @@ def main() -> None:
     print(f"driver path : buffer[-1] = {driver_sum[-1]:.1f}")
     print(f"spark-mpi   : buffer[-1] = {np.asarray(mpi_sum)[-1]:.1f}")
     assert np.allclose(driver_sum, np.asarray(mpi_sum))
-    print("identical results; see benchmarks/bench_allreduce.py for Table I")
+    print("identical results")
 
 
 if __name__ == "__main__":

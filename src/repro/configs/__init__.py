@@ -4,7 +4,7 @@
 module exports ``CONFIG`` (exact published numbers) and ``reduced()`` (tiny
 same-family smoke variant). ``input_specs`` builds ShapeDtypeStruct stand-ins
 for each cell — weak-type-correct, shardable, zero allocation — consumed by
-the multi-pod dry-run and roofline harness.
+``training.lower_cell``.
 """
 from __future__ import annotations
 
@@ -13,9 +13,8 @@ import importlib
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import (SHAPES, SMOKE_SHAPE, ModelConfig,
-                                OptimizerConfig, RunConfig, ShapeConfig,
-                                applicable_shapes)
+from repro.configs.base import (ModelConfig, OptimizerConfig, RunConfig,
+                                ShapeConfig)
 
 ARCHS: dict[str, str] = {
     "llava-next-34b": "repro.configs.llava_next_34b",
@@ -77,7 +76,8 @@ def input_specs(config: ModelConfig, shape: ShapeConfig) -> dict:
 
 
 def batch_specs_logical(config: ModelConfig, shape: ShapeConfig) -> dict:
-    """Logical sharding axes for the input batch (dry-run in_shardings)."""
+    """Logical sharding axes for the input batch (``lower_cell``'s
+    in_shardings)."""
     if shape.kind in ("train", "prefill"):
         if config.family == "vlm":
             return {"batch": {"tokens": ("batch", "seq"),
@@ -93,7 +93,6 @@ def batch_specs_logical(config: ModelConfig, shape: ShapeConfig) -> dict:
 
 
 __all__ = [
-    "ARCHS", "SHAPES", "SMOKE_SHAPE", "ModelConfig", "OptimizerConfig",
-    "RunConfig", "ShapeConfig", "all_archs", "applicable_shapes",
-    "batch_specs_logical", "get_config", "input_specs",
+    "ARCHS", "ModelConfig", "OptimizerConfig", "RunConfig", "ShapeConfig",
+    "all_archs", "batch_specs_logical", "get_config", "input_specs",
 ]

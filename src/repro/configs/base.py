@@ -78,10 +78,6 @@ class ModelConfig:
     def activation_dtype(self):
         return jnp.dtype(self.dtype)
 
-    @property
-    def is_subquadratic(self) -> bool:
-        return self.family in ("ssm", "hybrid")
-
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
@@ -92,25 +88,6 @@ class ShapeConfig:
     seq_len: int
     global_batch: int
     kind: str                      # train | prefill | decode
-
-
-# The assigned shape set (identical across the LM pool).
-SHAPES: dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
-
-SMOKE_SHAPE = ShapeConfig("smoke", 64, 2, "train")
-
-
-def applicable_shapes(config: ModelConfig) -> list[str]:
-    """Which assigned shapes run for this arch (skips recorded in DESIGN.md)."""
-    names = ["train_4k", "prefill_32k", "decode_32k"]
-    if config.is_subquadratic:
-        names.append("long_500k")
-    return names
 
 
 @dataclass(frozen=True)

@@ -2,8 +2,7 @@
 vocab=163840, MoE 384 experts top-8 — trillion-param MoE (paper-table).
 Sharding: experts over 'model' (EP) + expert d_model over 'data' (FSDP) +
 embeddings FSDP — 1T bf16 params => ~8 GB/chip at 256-way weight sharding
-(see EXPERIMENTS.md §Dry-run for measured bytes). [arXiv:2501.kimi2;
-unverified]
+(arithmetic, not measured). [arXiv:2501.kimi2; unverified]
 """
 from repro.configs.base import ModelConfig
 

@@ -364,10 +364,9 @@ class Broker:
                      max_bytes: int = 4 * 1024 * 1024) -> dict:
         """One whole replication round in one round trip — a chatty
         follower polling ``topics`` + per-partition :meth:`fetch_frames` +
-        :meth:`replica_hwm` every few milliseconds measurably taxes the
-        produce hot path it shares the broker with (see
-        ``bench_ingest:replication_overhead``); this op folds the round
-        into a single request. ``cursors`` is the follower's ``{topic:
+        :meth:`replica_hwm` every few milliseconds would tax the produce
+        hot path it shares the broker with; this op folds the round into a
+        single request. ``cursors`` is the follower's ``{topic:
         [next_offset per partition]}`` — it doubles as the high-watermark
         report (what the follower has IS what is safely replicated).
         Returns ``{"topics": {topic: n_partitions}, "parts": {topic:

@@ -64,8 +64,7 @@ class IngestConfig:
     rate_limit: float | None = None  # producer-side cap, records/s
     # Batched produce: polled records buffer until one of these trips, then
     # flush as one produce_many per partition (one transport frame instead of
-    # one per record — the fast path bench_ingest prices). flush_records=1
-    # restores PR 2's per-record produce.
+    # one per record). flush_records=1 restores per-record produce.
     flush_records: int = 64        # flush when this many records buffered
     flush_bytes: int = 1 << 20     # ... or the buffered payload estimate hits
     flush_interval: float = 0.02   # ... or the oldest buffered record ages out

@@ -35,10 +35,9 @@ object.
 
 Hot-path cost discipline: incrementing a counter is one lock + one add, and
 the instrumented layers cache their instruments at construction (no registry
-lookup per record). ``benchmarks/run.py --check`` guards the total tax:
-ingest with the registry on must stay within 1.1x of registry-off records/s.
-The off switch is :class:`NullRegistry` (every operation a no-op) installed
-via :func:`set_registry` / :func:`disabled`.
+lookup per record). No chip cell measures the total tax yet. The off
+switch is :class:`NullRegistry` (every operation a no-op) installed via
+:func:`set_registry` / :func:`disabled`.
 
 **Batch-epoch trace spans** (:class:`TraceLog`, :class:`BatchSpan`): the
 streaming context stamps one span per micro-batch — pump, batch fn, serial

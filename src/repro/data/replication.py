@@ -31,18 +31,17 @@ over the repo's own primitives (``docs/replication.md`` is the full story):
   (``fence`` op → :class:`~repro.core.broker.BrokerFencedError` on every
   write a zombie would otherwise accept).
 
-Durability contract (the crash window, quantified by
-``bench_ingest:failover_gap``): replication is asynchronous — a batch acked
-by the primary may not have reached the follower when the primary dies. The
-client therefore keeps every produced batch in a *resend window* until a
-follower's reported high-watermark covers it; on failover the window is
-re-sent to the new primary. Combined with the idempotent-by-key sinks
-downstream this means **no committed record is lost and duplicates are
-absorbed**: at-least-once across a failover, exactly-once end-to-end — the
-same contract a plain :class:`~repro.data.transport.RemoteBroker` retry
-already has. With no follower attached ``replica_hwm`` is empty and the
-window collapses to "primary ack = committed", i.e. exactly the pre-HA
-behavior.
+Durability contract (the crash window): replication is asynchronous — a
+batch acked by the primary may not have reached the follower when the
+primary dies. The client therefore keeps every produced batch in a *resend
+window* until a follower's reported high-watermark covers it; on failover
+the window is re-sent to the new primary. Combined with the
+idempotent-by-key sinks downstream this means **no committed record is
+lost and duplicates are absorbed**: at-least-once across a failover,
+exactly-once end-to-end — the same contract a plain
+:class:`~repro.data.transport.RemoteBroker` retry already has. With no
+follower attached ``replica_hwm`` is empty and the window collapses to
+"primary ack = committed", i.e. exactly the pre-HA behavior.
 """
 from __future__ import annotations
 
@@ -286,11 +285,9 @@ class ReplicaFollower:
             except (KeyError, ValueError) as e:
                 log.warning("replication pull skipped a round: %s", e)
             # pace every round: back-to-back pulls against a live primary
-            # measurably tax its produce hot path (the guard in
-            # bench_ingest:replication_overhead), and one max_bytes-sized
-            # pull per poll_interval already sustains ~200 MB/s at the
-            # defaults — replication lag is bounded by one poll plus one
-            # transfer, not by how often the follower can hammer the wire
+            # would tax its produce hot path, and replication lag is bounded
+            # by one poll plus one max_bytes-sized transfer, not by how
+            # often the follower can hammer the wire
             self._stop.wait(self.poll_interval)
 
 

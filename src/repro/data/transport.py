@@ -1080,11 +1080,10 @@ class RemoteBroker:
                 partition: int | None = None, timestamp: float = 0.0) -> int:
         """Append one record; returns its partition-local offset.
 
-        One request/response round trip per record — the per-record cost
-        `bench_ingest` prices as ``ingest/remote_transport``. Hot paths
-        should batch with :meth:`produce_many` instead (one frame per batch).
-        Delivery is at-least-once: a retry whose ack was lost appends the
-        record twice; idempotent-by-key sinks dedupe downstream.
+        One request/response round trip per record. Hot paths should batch
+        with :meth:`produce_many` instead (one frame per batch). Delivery
+        is at-least-once: a retry whose ack was lost appends the record
+        twice; idempotent-by-key sinks dedupe downstream.
         """
         return self._request("produce", topic, value, key=key,
                              partition=partition, timestamp=timestamp)

@@ -1,1 +1,1 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve drivers."""
+"""Launchers: train/serve drivers and the HLO cost walk."""

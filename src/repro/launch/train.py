@@ -9,8 +9,8 @@ checkpoint.
     PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b \
         --reduced --steps 50 --batch 4 --seq 128 --ckpt-dir /tmp/ck
 
-Full-scale configs are exercised via launch/dryrun.py (this container is one
-CPU); --reduced runs the real loop on the reduced config.
+--reduced runs the real loop on the reduced config; full-scale configs need
+a mesh of their size.
 """
 from __future__ import annotations
 

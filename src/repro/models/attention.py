@@ -3,7 +3,7 @@
 * ``naive``   — full score matrix; oracle for tests and small shapes.
 * ``blocked`` — double-scan online-softmax (flash-style dataflow in pure JAX):
                 O(block_q × block_kv) live scores, exact same math. This is
-                the default for dry-runs/long sequences on any backend.
+                the default for long sequences on any backend.
 * ``pallas``  — the TPU flash kernel in ``kernels/flash_attention`` (same
                 blocking, VMEM-resident); validated against ``naive`` in
                 interpret mode, selected via ``attention_impl='pallas'``.
@@ -13,7 +13,7 @@ after RoPE), keeping every attention tensor 4-D (B, S, H, hd) — the only
 layout where TP-by-heads shards cleanly under GSPMD (a grouped 5-D
 (B, S, KH, G, hd) layout splits the 'model' axis across two dims, which
 GSPMD cannot express; it then invents cross-shard contractions — observed
-as per-tile all-reduces in the dry-run, see EXPERIMENTS.md §Perf). The
+as per-tile all-reduces in the lowered HLO). The
 repeat is free on the wire (slices locally) and the Pallas kernel avoids
 the HBM copy on real hardware.
 """

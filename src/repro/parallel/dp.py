@@ -19,7 +19,6 @@ tested against the fused-GSPMD trainer in tests/test_dp.py.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any
 
 import jax
@@ -164,19 +163,3 @@ def build_dp_train_step(config: ModelConfig, opt: OptimizerConfig,
                        check_vma=False)
     return jax.jit(sm, donate_argnums=(0,)), state_specs
 
-
-def lower_dp_cell(config: ModelConfig, shape, mesh: Mesh,
-                  opt: OptimizerConfig | None = None,
-                  compression: str | None = None):
-    """Lower the explicit-collective DP train step for the dry-run/walker."""
-    from repro.configs import input_specs
-    opt = opt or OptimizerConfig()
-    model = get_model(config)
-    jitted, _ = build_dp_train_step(config, opt, mesh, compression)
-    param_shapes = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), config))
-    opt_shapes = jax.eval_shape(
-        functools.partial(init_dp_opt_state, mesh=mesh, opt=opt),
-        param_shapes)
-    return jitted.lower({"params": param_shapes, "opt": opt_shapes},
-                        input_specs(config, shape)["batch"])

@@ -1,10 +1,10 @@
 """GPipe pipeline parallelism over the 'pod' axis.
 
-Cross-pod DCN is ~10× slower than ICI, so the multi-pod mesh wants the
-parallelism with the *least* inter-pod traffic. DP moves 2×params of
-gradients per step over DCN; pipeline parallelism moves only microbatch
-activations (B_mb·S·D per boundary per tick) — for the 1T config that is
-three orders of magnitude less wire.
+Cross-pod DCN has a fraction of ICI's bandwidth, so the multi-pod mesh
+wants the parallelism with the *least* inter-pod traffic. DP moves
+2×params of gradients per step over DCN; pipeline parallelism moves only
+microbatch activations (B_mb·S·D per boundary per tick) — for the 1T
+config that is three orders of magnitude less wire.
 
 Implementation: ``shard_map`` manual over 'pod' only (``axis_names=
 {'pod'}``) — GSPMD keeps handling data/model INSIDE each stage, so TP/DP

@@ -2,9 +2,9 @@
 
 ``build_train_step``/``build_serve_fns`` produce the pure step functions;
 ``shardings_for``/``lower_*`` attach PartitionSpecs for a concrete mesh —
-used identically by the real trainer (``launch/train.py``), the streaming
-pipeline (train-on-stream), and the multi-pod dry-run
-(``launch/dryrun.py`` lowers the same functions at full scale).
+used identically by the real trainer (``launch/train.py``) and the streaming
+pipeline (train-on-stream); ``lower_cell`` lowers the same functions for a
+mesh without allocating.
 """
 from __future__ import annotations
 
@@ -127,7 +127,7 @@ def shardings_for(config: ModelConfig, shape: ShapeConfig, mesh: Mesh,
     return cell
 
 
-# -- lowering (dry-run entry points) ---------------------------------------------
+# -- lowering (no allocation) ------------------------------------------------
 def lower_cell(config: ModelConfig, shape: ShapeConfig, mesh: Mesh,
                opt: OptimizerConfig | None = None):
     """Lower the cell's step function at full scale (no allocation).

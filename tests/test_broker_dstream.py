@@ -289,3 +289,34 @@ def test_realtime_report():
     rep = sc.realtime_report()
     assert rep["batches"] == 4 and rep["records"] == 20
     assert rep["keeps_up"] is True
+
+
+def test_microbatch_allreduce_per_batch():
+    """Paper Figs. 7-8: each micro-batch of a two-topic union becomes one
+    collective job. The batch's sum, allreduced over the bridge's ranks, is
+    world times that sum, and every record is seen once."""
+    from repro.core import MPIBridge
+
+    b = Broker()
+    for t in range(2):
+        b.create_topic(f"topic-{t}", 1)
+    for i in range(40):
+        b.produce(f"topic-{i % 2}", np.float32(i))
+    ctx = Context()
+    bridge = MPIBridge()
+    sc = StreamingContext(ctx, b, max_records_per_partition=5)
+    sc.subscribe(["topic-0", "topic-1"])
+    sums = []
+
+    def on_batch(rdd, info):
+        total = np.float32(np.sum(rdd.collect()))
+        part = ctx.from_partitions([np.full(16, total, np.float32)]
+                                   * bridge.world)
+        reduced = np.asarray(bridge.allreduce(part))
+        np.testing.assert_allclose(reduced, bridge.world * total)
+        sums.append(total)
+
+    sc.foreach_batch(on_batch)
+    sc.run_batches(4)
+    assert len(sums) == 4
+    assert float(np.sum(sums)) == float(sum(range(40)))

@@ -213,6 +213,41 @@ def test_ingest_encodes_subscribe_decodes():
         assert np.max(np.abs(frame - ref)) <= np.max(np.abs(ref)) / 127 + 1e-9
 
 
+def test_ingest_codec_values_cross_the_socket_encoded(tmp_path):
+    """Encode-at-flush through the socket transport: every record lands on
+    the served broker's log still codec-tagged, none lost, and decodes
+    within the int8 bound."""
+    from repro.data import IngestConfig, IngestRunner, SyntheticRateSource
+    from repro.data.transport import RemoteBroker, serve_broker
+
+    frame = np.random.default_rng(0).standard_normal(
+        (16, 16)).astype(np.float32)
+    broker = Broker()
+    server = serve_broker(broker, str(tmp_path / "b.sock"))
+    client = RemoteBroker(server.address, shm=False)
+    try:
+        runner = IngestRunner(client)
+        m = runner.add(SyntheticRateSource(rate=1e9, total=48,
+                                           value_fn=lambda i: (i, frame * i)),
+                       IngestConfig(topic="t", partitions=1, poll_batch=16,
+                                    flush_records=16, max_pending=1 << 30,
+                                    codec="int8"))
+        runner.run_inline(timeout=60)
+        assert m.produced == 48
+        assert broker.end_offsets("t") == [48]
+        raw = broker.read(OffsetRange("t", 0, 0, 48))
+        assert all(isinstance(r.value, dict) and r.value[SENTINEL] == "int8"
+                   for r in raw)
+        for rec in raw:
+            i, got = maybe_decode(rec.value)
+            ref = frame * i
+            bound = np.max(np.abs(ref)) / 127 + 1e-6
+            assert np.max(np.abs(got - ref)) <= bound
+    finally:
+        client.close()
+        server.stop()
+
+
 def test_topic_source_decodes_for_chained_stages():
     from repro.data import TopicSource
 

@@ -202,6 +202,44 @@ def test_set_registry_returns_previous_and_disabled_restores():
     assert get_registry() is base
 
 
+@pytest.mark.parametrize("registry_on", [True, False])
+def test_ingest_drains_every_record_with_registry_on_and_off(registry_on):
+    """The instrumented ingest path and the same path built under
+    disabled() deliver the same records: telemetry observes, never alters."""
+    from repro.core import Broker, Context, StreamingContext
+    from repro.data import IngestConfig, IngestRunner, SyntheticRateSource
+
+    def drain() -> list:
+        broker = Broker()
+        sc = StreamingContext(Context(), broker, max_records_per_partition=20)
+        runner = IngestRunner(broker, consumer=sc)
+        runner.add(SyntheticRateSource(rate=1e9, total=400),
+                   IngestConfig(topic="t", partitions=2, poll_batch=40))
+        sc.subscribe(["t"])
+        got: list = []
+        sc.foreach_batch(lambda rdd, info: got.extend(rdd.collect()))
+        while not runner.done or sc.lag("t") > 0:
+            runner.pump()
+            sc.run_one_batch()
+        assert sum(b.num_records for b in sc.history) == 400
+        return got
+
+    if registry_on:
+        reg = MetricsRegistry()
+        prev = set_registry(reg)
+        try:
+            got = drain()
+        finally:
+            set_registry(prev)
+        produced = reg.counter("ingest_produced_records_total",
+                               labels={"topic": "t"})
+        assert produced.value() == 400
+    else:
+        with disabled():
+            got = drain()
+    assert sorted(got) == list(range(400))
+
+
 # -- trace spans --------------------------------------------------------------
 
 def test_span_stages_cover_the_documented_pipeline_order():

@@ -1,6 +1,6 @@
 """Multi-device semantics (bridge collectives, elastic recovery, hlocost
-collectives, dry-run smoke) — run in subprocesses with 8 virtual devices so
-the main pytest process keeps its single real device."""
+collectives, cell-lowering smoke) — run in subprocesses with 8 virtual
+devices so the main pytest process keeps its single real device."""
 import json
 import os
 import subprocess
@@ -173,9 +173,9 @@ def test_hlocost_collectives_at_mesh_sizes():
 
 
 def test_dryrun_cell_smoke_small_mesh():
-    """The dry-run path end-to-end on a (2, 2, 2) multi-pod mini-mesh with a
-    reduced config — validates lower+compile+walker wiring without the
-    512-device cost (the full meshes run via launch/dryrun.py)."""
+    """Lowering a cell end-to-end on a (2, 2, 2) multi-pod mini-mesh with a
+    reduced config — validates lower+compile+walker wiring at a size that
+    fits in one test process."""
     out = run_with_devices("""
         import jax
         from repro.configs import get_config

@@ -406,6 +406,80 @@ def test_ingest_batches_produce_over_remote(tmp_path):
         server.stop()
 
 
+@pytest.mark.parametrize("flush_records", [1, 50])
+def test_remote_ingest_with_server_side_lag_delivers_every_record(
+        tmp_path, flush_records):
+    """The split deployment: IngestRunner produces through RemoteBroker and
+    reads its backpressure lag from the served broker, where a local
+    StreamingContext commits. Per-record and batched produce both deliver
+    every record once, and the commits reach the log's end."""
+    from repro.data import IngestConfig, IngestRunner, SyntheticRateSource
+
+    broker = Broker()
+    server = serve_broker(broker, str(tmp_path / "b.sock"))
+    client = RemoteBroker(server.address)
+    try:
+        runner = IngestRunner(client, consumer=client)
+        m = runner.add(SyntheticRateSource(rate=1e9, total=300),
+                       IngestConfig(topic="t", partitions=2, poll_batch=50,
+                                    max_pending=200,
+                                    flush_records=flush_records))
+        sc = StreamingContext(Context(), broker, max_records_per_partition=25)
+        sc.subscribe(["t"])
+        got = []
+        sc.foreach_batch(lambda rdd, info: got.extend(rdd.collect()))
+        ticks = 0
+        while not runner.done or sc.lag("t") > 0:
+            runner.pump()
+            sc.run_one_batch()
+            ticks += 1
+            assert ticks < 1000, "pipeline never drained"
+        assert m.produced == 300 and m.dropped == 0
+        assert sorted(got) == list(range(300))
+        assert broker.committed("t") == broker.end_offsets("t")
+        assert client.lag("t") == 0
+    finally:
+        client.close()
+        server.stop()
+
+
+@pytest.mark.parametrize("array_frames", [True, False])
+def test_ingest_ndarray_payloads_over_remote(tmp_path, monkeypatch,
+                                             array_frames):
+    """Detector-style ndarray values, produced in batches through the
+    socket, arrive whole and exact whether they ride zero-copy 'A' frames
+    or the pickled 'P' fallback."""
+    np = pytest.importorskip("numpy")
+    import repro.data.transport as tr
+    from repro.data import IngestConfig, IngestRunner, SyntheticRateSource
+
+    monkeypatch.setattr(tr, "USE_ARRAY_FRAMES", array_frames)
+    frame = np.random.default_rng(0).standard_normal(
+        (32, 32)).astype(np.float32)
+    broker = Broker()
+    server = serve_broker(broker, str(tmp_path / "b.sock"))
+    client = RemoteBroker(server.address, shm=False)
+    try:
+        runner = IngestRunner(client)
+        m = runner.add(SyntheticRateSource(rate=1e9, total=40,
+                                           value_fn=lambda i: (i, frame * i)),
+                       IngestConfig(topic="t", partitions=2, poll_batch=10,
+                                    flush_records=10, max_pending=1 << 30))
+        runner.run_inline(timeout=60)
+        assert m.produced == 40
+        assert sum(broker.end_offsets("t")) == 40
+        seen = []
+        for p in range(2):
+            for rec in broker.read(OffsetRange("t", p, 0, 999)):
+                i, got = rec.value
+                np.testing.assert_array_equal(got, frame * i)
+                seen.append(i)
+        assert sorted(seen) == list(range(40))
+    finally:
+        client.close()
+        server.stop()
+
+
 def test_ingest_flush_deadline_and_done():
     """A partially-filled buffer flushes when the oldest record ages past
     flush_interval, and done stays False until the buffer drains."""
