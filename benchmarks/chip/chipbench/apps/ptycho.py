@@ -19,6 +19,11 @@ far-field magnitudes in one jitted call on the device) and runs one scan
 through the pipeline with one iteration per batch and one refinement
 iteration: the iteration number is a traced argument, so that touches
 every program the window runs.
+
+Each scan's published iterations stay on the device until the scan ends;
+then a reservoir of one, drawn from the seed, keeps them or drops them. So
+the device holds the published iterations of two scans at most, the kept
+one and the one in progress, however many scans the window runs.
 """
 from __future__ import annotations
 
@@ -88,6 +93,18 @@ def make_measurements(key: jax.Array, positions: jax.Array,
     return jnp.abs(jnp.fft.fft2(probe * patches)).astype(jnp.float32)
 
 
+def reservoir(seed: int) -> np.random.Generator:
+    """The generator of the reservoir's draws: from ``seed``, apart from the
+    streams that make the inputs and that the check draws."""
+    return np.random.default_rng(np.random.SeedSequence(seed).spawn(1)[0])
+
+
+def keep_new(k: int, rng: np.random.Generator) -> bool:
+    """Whether the ``k``-th scan (from 1) takes the reservoir's one place:
+    with probability 1/k, so each of ``k`` scans is kept with share 1/k."""
+    return bool(rng.integers(k) == 0)
+
+
 class StampedDetector(DetectorSource):
     """The detector, with the time each frame was released recorded."""
 
@@ -129,10 +146,13 @@ class App:
         self.scan = 0
         self.batches: list[dict[str, Any]] = []
         self.scans: list[dict[str, Any]] = []
-        # per scan, each published iteration (the last of each batch and of
-        # the refinement) as the device holds it: input waves, probe and
-        # iteration number, output waves and probe
-        self.steps: dict[int, list[tuple[Any, ...]]] = {}
+        # the scan in progress's published iterations (the last of each batch
+        # and of the refinement) as the device holds them: input waves, probe
+        # and iteration number, output waves and probe
+        self.steps: list[tuple[Any, ...]] = []
+        # the reservoir: (scan, its steps) of the scan the check compares
+        self.kept: tuple[int, list[tuple[Any, ...]]] | None = None
+        self.reservoir = reservoir(seed)
 
     # -- set-up -------------------------------------------------------------
     def make_inputs(self) -> None:
@@ -175,7 +195,6 @@ class App:
                    (self.sink, SinkPolicy.retry(2, queue_depth=32))])
         self.pipeline.subscribe_source(self.source, topic="frames")
         self.run_unit(iters=1, refine=1)  # warm-up scan: every program
-        self.steps.clear()
 
     # -- the timed path -----------------------------------------------------
     def _process(self, rdd, info, bridge):
@@ -208,8 +227,7 @@ class App:
             psi_in, probe_in, it = psi, st["probe"], st["iteration"]
             psi, obj, probe, err = self.step(psi, mags, pos, probe_in, it)
             if i == self._iters - 1:       # the published iteration
-                self.steps.setdefault(scan, []).append(
-                    (psi_in, probe_in, it, psi, probe))
+                self.steps.append((psi_in, probe_in, it, psi, probe))
             st["probe"] = probe
             st["iteration"] += 1
         st.update(psi=psi, obj=obj, mags=mags, pos=pos,
@@ -242,8 +260,7 @@ class App:
                 psi, obj, probe, err = self.step(psi, st["mags"], st["pos"],
                                                  probe_in, it)
                 if i == refine - 1:
-                    self.steps.setdefault(scan, []).append(
-                        (psi_in, probe_in, it, psi, probe))
+                    self.steps.append((psi_in, probe_in, it, psi, probe))
             obj = np.asarray(obj)
         value = {"obj": obj, "fourier_err": np.float32(err)}
         key = f"scan-{scan:06d}-final"
@@ -251,22 +268,24 @@ class App:
         self.scans.append({"scan": scan, "start": t0,
                            "end": self.sink.stamps[key]})
         self.scan += 1
+        steps, self.steps = self.steps, []
+        k = len(self.scans) - 1            # scans completed after set-up
+        if k and keep_new(k, self.reservoir):
+            self.kept = (scan, steps)
 
     def close(self) -> None:
         self.pipeline.close()
 
     def release(self) -> None:
-        """Free the device state; the scan the check compares, drawn from the
-        seed among those the run completed after set-up, keeps its published
-        iterations' inputs on the host."""
-        scans = [u["scan"] for u in self.scans][1:]
-        rng = np.random.default_rng(self.seed)
-        self.sampled = int(scans[rng.integers(len(scans))])
+        """Free the device state; the scan the check compares, the one the
+        reservoir kept (uniform over those the run completed after set-up),
+        keeps its published iterations on the host."""
+        self.sampled, steps = self.kept
         self.sampled_steps = [
             (np.asarray(p), np.asarray(q), it, np.asarray(p1), np.asarray(q1))
-            for p, q, it, p1, q1 in self.steps[self.sampled]]
+            for p, q, it, p1, q1 in steps]
         self.state.clear()
-        self.steps.clear()
+        self.kept = None
         del self.mags, self.pos_dev
 
     # -- what the window did --------------------------------------------------

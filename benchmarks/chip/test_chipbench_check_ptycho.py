@@ -1,11 +1,15 @@
 """The ptychography cell's run, past the look for a chip, at a small size on
 the CPU: a sound run is correct and compiles nothing in its window; a run
-with the timed path broken underneath is not correct; and the control, the
+with the timed path broken underneath is not correct; the control, the
 plain reference in bfloat16, fails the configuration's limits where the
-program passes them."""
+program passes them; and the scan the check compares is drawn uniformly by
+a reservoir of one, so the device holds two scans' published iterations at
+most."""
 import dataclasses
+import gc
 import time
 
+import jax
 import jax.numpy as jnp
 import ml_dtypes
 import numpy as np
@@ -63,6 +67,54 @@ def test_warm_up_leaves_nothing_to_compile(tmp_path):
     app.run_unit()
     app.close()
     assert counter.programs == 0
+
+
+def _reservoir_keeps(seed, scans=5):
+    rng = ptycho.reservoir(seed)
+    kept = None
+    for k in range(1, scans + 1):
+        if ptycho.keep_new(k, rng):
+            kept = k
+    return kept
+
+
+def test_reservoir_keeps_each_scan_with_equal_share():
+    kept = np.bincount([_reservoir_keeps(seed) for seed in range(10_000)],
+                       minlength=6)[1:]
+    assert np.all(np.abs(kept / 10_000 - 0.2) <= 0.02), kept
+
+
+def test_reservoir_same_seed_keeps_same_scan():
+    seeds = [2 ** 31 + 11, 3, 12345]
+    assert ([_reservoir_keeps(s, 40) for s in seeds]
+            == [_reservoir_keeps(s, 40) for s in seeds])
+    assert len({_reservoir_keeps(s, 40) for s in range(20)}) > 1
+
+
+def _live_waves(n):
+    """Complex frames of ``n``² on the device: the waves of any scan."""
+    gc.collect()
+    return sum(a.ndim == 3 and a.shape[1:] == (n, n)
+               and a.dtype == jnp.complex64 for a in jax.live_arrays())
+
+
+def test_device_holds_at_most_two_scans_of_published_iterations(tmp_path):
+    cell = _small_cell()
+    n = CONFIG["probe_size"]
+    published = CONFIG["frames_per_scan"] // TRAFFIC["batch_frames"] + 1
+    base = _live_waves(n)
+    app = ptycho.App(cell.config, cell.traffic, 2 ** 31 + 5, str(tmp_path))
+    app.setup()
+    for _ in range(6):
+        app.run_unit()
+        assert app.steps == [] and app.kept is not None
+        assert len(app.kept[1]) == published
+        # each published iteration holds its input and output waves
+        assert _live_waves(n) - base <= 2 * 2 * published
+    app.close()
+    app.release()
+    assert app.sampled in [u["scan"] for u in app.scans][1:]
+    assert len(app.sampled_steps) == published
 
 
 def _state_unchanged(step):
